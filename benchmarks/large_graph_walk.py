@@ -89,6 +89,7 @@ PAPER_CLAIM = (
 )
 
 PARAMS = MHLJParams(p_j=0.1, p_d=0.5, r=3)
+TIMED_RUNS = 5  # timed repeats per configuration (see _best_seconds)
 
 # Engine configurations swept per family: label -> from_graph overrides.
 # "bucketed" is the uncompacted dispatch (compact=False) so the sweep
@@ -164,10 +165,28 @@ def _resident_table_bytes(engine: WalkEngine) -> int:
     return total
 
 
-def _sweep_one(
+def _best_seconds(calls):
+    """Seconds of the fastest of ``TIMED_RUNS`` warm calls of each
+    ``(fn, args)`` in ``calls``, and each one's last outputs.  The calls
+    are timed round-robin: a smoke-size run lasts about a millisecond, so
+    a slow spell of a busy host would otherwise fall on one configuration
+    alone and decide the regression gate's ratios."""
+    best = [float("inf")] * len(calls)
+    outs = [None] * len(calls)
+    for _ in range(TIMED_RUNS):
+        for i, (fn, args) in enumerate(calls):
+            t0 = time.perf_counter()
+            outs[i] = jax.block_until_ready(fn(*args))
+            best[i] = min(best[i], time.perf_counter() - t0)
+    return best, outs
+
+
+def _prepare_one(
     graph, num_walks: int, num_steps: int, seed: int, label: str,
-    backend: str = "auto",
-) -> dict:
+    backend: str,
+):
+    """The engine of configuration ``label`` and its compiled, warmed
+    trajectory call ``(run, args)``."""
     cfg = dict(CONFIGS[label])
     layout = cfg.pop("layout")
     rng = np.random.default_rng(seed)
@@ -178,7 +197,6 @@ def _sweep_one(
         graph, PARAMS, lipschitz=lips, backend=backend, layout=layout, **cfg
     )
     v0s = jnp.asarray(rng.integers(0, graph.n, num_walks), jnp.int32)
-    key = jax.random.PRNGKey(seed)
 
     # jit the whole trajectory, exactly like the production consumers
     # (walk_sgd.trainer scans the engine inside one jitted loop) — timing
@@ -186,42 +204,55 @@ def _sweep_one(
     # not the engine.  with_aux threads out the per-step compaction
     # telemetry (overflow flags) at no extra cost on the other layouts.
     run = jax.jit(lambda k, v: engine.run(k, v, num_steps, with_aux=True))
-    nodes, hops, aux = run(key, v0s)  # compile + warm
-    nodes.block_until_ready()
-    t0 = time.perf_counter()
-    nodes, hops, aux = run(jax.random.PRNGKey(seed + 1), v0s)
-    nodes.block_until_ready()
-    dt = time.perf_counter() - t0
+    jax.block_until_ready(run(jax.random.PRNGKey(seed), v0s))  # compile + warm
+    return engine, (run, (jax.random.PRNGKey(seed + 1), v0s))
 
-    hops_np = np.asarray(hops, np.float64)
-    bucketed = layout == "bucketed"
-    compacted = bucketed and bool(engine.compact)
-    return {
-        "label": label,
-        "layout": layout,
-        "compact": bool(engine.compact) if bucketed else None,
-        "n": graph.n,
-        "nnz": graph.num_edges,
-        "max_degree": graph.max_degree,
-        "bucket_widths": (
-            [nb.shape[1] for nb in engine.bucket_neighbors] if bucketed
-            else None
-        ),
-        "num_walks": num_walks,
-        "num_steps": num_steps,
-        "walk_steps_per_sec": float(num_walks * num_steps / dt),
-        "transitions_per_update": float(hops_np.mean()),
-        # fraction of steps whose compacted dispatch overflowed a static
-        # bucket capacity and lax.cond fell back to the full-W dispatch —
-        # the audit trail of the engine.bucket_capacities rule
-        "compact_overflow_rate": (
-            float(np.asarray(aux["compact_overflow"], np.float64).mean())
-            if compacted else None
-        ),
-        "resident_table_bytes": _resident_table_bytes(engine),
-        "csr_bytes": int(graph.indptr.nbytes + graph.indices.nbytes),
-        "dense_table_bytes_avoided": int(graph.n) ** 2 * 8,
-    }
+
+def _sweep_family(
+    graph, labels, num_walks: int, num_steps: int, seed: int,
+    backend: str = "auto",
+) -> dict:
+    """One result row per configuration in ``labels``, timed together."""
+    prepared = [
+        _prepare_one(graph, num_walks, num_steps, seed, label, backend)
+        for label in labels
+    ]
+    seconds, outs = _best_seconds([call for _, call in prepared])
+    rows = {}
+    for label, (engine, _), dt, (_nodes, hops, aux) in zip(
+        labels, prepared, seconds, outs
+    ):
+        layout = CONFIGS[label]["layout"]
+        hops_np = np.asarray(hops, np.float64)
+        bucketed = layout == "bucketed"
+        compacted = bucketed and bool(engine.compact)
+        rows[label] = {
+            "label": label,
+            "layout": layout,
+            "compact": bool(engine.compact) if bucketed else None,
+            "n": graph.n,
+            "nnz": graph.num_edges,
+            "max_degree": graph.max_degree,
+            "bucket_widths": (
+                [nb.shape[1] for nb in engine.bucket_neighbors] if bucketed
+                else None
+            ),
+            "num_walks": num_walks,
+            "num_steps": num_steps,
+            "walk_steps_per_sec": float(num_walks * num_steps / dt),
+            "transitions_per_update": float(hops_np.mean()),
+            # fraction of steps whose compacted dispatch overflowed a static
+            # bucket capacity and lax.cond fell back to the full-W dispatch
+            # — the audit trail of the engine.bucket_capacities rule
+            "compact_overflow_rate": (
+                float(np.asarray(aux["compact_overflow"], np.float64).mean())
+                if compacted else None
+            ),
+            "resident_table_bytes": _resident_table_bytes(engine),
+            "csr_bytes": int(graph.indptr.nbytes + graph.indices.nbytes),
+            "dense_table_bytes_avoided": int(graph.n) ** 2 * 8,
+        }
+    return rows
 
 
 def _fleet_sweep(scale: str) -> tuple[dict, dict]:
@@ -263,6 +294,7 @@ def _fleet_sweep(scale: str) -> tuple[dict, dict]:
     fleet: dict = {"mesh_devices": n_dev, "graph_n": graph.n,
                    "layout": "ragged", "backend": "scan"}
     derived: dict = {"fleet_mesh_devices": n_dev}
+    calls, sharded = [], []
     for w in fleet_sizes:
         sharding = resolve_walker_axis(w, mesh)
         eng_w = (
@@ -275,16 +307,15 @@ def _fleet_sweep(scale: str) -> tuple[dict, dict]:
         run_fn = jax.jit(
             lambda k, v, e=eng_w: e.run(k, v, num_steps)
         )
-        nodes, _ = run_fn(jax.random.PRNGKey(3), v0s)  # compile + warm
-        nodes.block_until_ready()
-        t0 = time.perf_counter()
-        nodes, _ = run_fn(jax.random.PRNGKey(4), v0s)
-        nodes.block_until_ready()
-        dt = time.perf_counter() - t0
+        jax.block_until_ready(run_fn(jax.random.PRNGKey(3), v0s))  # warm
+        calls.append((run_fn, (jax.random.PRNGKey(4), v0s)))
+        sharded.append(sharding is not None)
+    seconds, _ = _best_seconds(calls)
+    for w, dt, is_sharded in zip(fleet_sizes, seconds, sharded):
         agg = float(w * num_steps / dt)
         fleet[f"w{w}"] = {
             "num_walkers": w,
-            "sharded": sharding is not None,
+            "sharded": is_sharded,
             "aggregate_walk_steps_per_sec": agg,
         }
         derived[f"fleet_w{w}_num_walkers"] = w
@@ -500,11 +531,10 @@ def run(quick: bool = False, scale: str | None = None) -> dict:
         # vectorized BA sampler rotting back to a per-node loop) is visible
         # where the smoke/regression tooling looks
         derived[f"{tag}_construction_sec"] = build_s
+        fam.update(_sweep_family(
+            graph, labels, num_walks, num_steps, seed=7, backend=backend,
+        ))
         for label in labels:
-            fam[label] = _sweep_one(
-                graph, num_walks, num_steps, seed=7, label=label,
-                backend=backend,
-            )
             derived[f"{tag}_{label}_steps_per_sec"] = (
                 fam[label]["walk_steps_per_sec"]
             )

@@ -94,6 +94,9 @@ def smoke(json_path: str | None = None) -> int:
 
 
 def main() -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", help="reduced sizes/iters")
     ap.add_argument(

@@ -29,8 +29,8 @@ Backends (identical law, bitwise-identical outputs given the same key):
 * ``"scan"``   — pure JAX ``vmap`` over walks; also the oracle for kernel
   tests.  Gathers only the W active P_IS rows, so it stays cheap for
   single-walk training loops.
-* ``"pallas"`` — the ``kernels/walk_transition`` TPU kernels; falls back to
-  ``interpret=True`` off-TPU.  Row handling is governed by ``layout``:
+* ``"pallas"`` — the ``kernels/walk_transition`` Pallas kernels, in
+  interpret mode off-TPU.  Row handling is governed by ``layout``:
   ``"sparse"`` (default) gathers only the W active ``[block_w, max_deg]``
   neighbor tiles and runs the MH CDF inversion in
   ``walk_transition_sparse`` with the Lévy hop chain as O(W) XLA gathers —
@@ -51,7 +51,7 @@ Backends (identical law, bitwise-identical outputs given the same key):
   padded and no per-bucket table), the MH inversion is a binary search of
   each walk's own CDF segment (:func:`ragged_mh_invert`, O(W·log max_deg)
   per step instead of O(W·max_deg)), and the pallas path is one fused
-  scalar-prefetch kernel per walk tile
+  scalar-core kernel per walk tile
   (``kernels.walk_transition.walk_transition_ragged``) that performs the
   inversion, the r-hop Lévy gather and the jump/MH combine in a single
   pass — no bucket ladder, no compaction argsort/scatter, no overflow
@@ -60,9 +60,14 @@ Backends (identical law, bitwise-identical outputs given the same key):
   original full-table-in-VMEM kernel for parity testing at orchestration
   scale (n <= a few thousand).  The registered layouts live in
   :data:`LAYOUTS`.
-* ``"auto"``   — pallas on TPU, scan elsewhere; overridable via the
-  ``REPRO_BACKEND`` environment variable (:data:`BACKEND_ENV_VAR`), which
-  is how the CI matrix forces each backend.  The scan backend also
+* ``"auto"``   — pallas on TPU for the layouts whose kernel the TPU
+  compiler accepts (:data:`TPU_PALLAS_LAYOUTS`: ragged only — the sparse,
+  bucketed and dense kernels take a ``cumsum`` Mosaic does not lower),
+  scan (XLA) for the others and everywhere off-TPU; an explicit
+  ``"pallas"`` on a rejected layout raises on TPU instead of dropping to
+  interpret mode (:attr:`WalkEngine.resolved_backend`).  Overridable via
+  the ``REPRO_BACKEND`` environment variable (:data:`BACKEND_ENV_VAR`),
+  which is how the CI matrix forces each backend.  The scan backend also
   services the bucketed layout (pure-jnp per-bucket dispatch, compacted
   the same way), so the bucketed path runs everywhere the engine runs.
 
@@ -85,6 +90,7 @@ walk (1 for an MH move, d for a Lévy jump).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 from typing import Optional, Tuple, Union
@@ -102,6 +108,7 @@ __all__ = [
     "U_DIST",
     "U_HOP0",
     "LAYOUTS",
+    "TPU_PALLAS_LAYOUTS",
     "BACKEND_ENV_VAR",
     "num_uniforms",
     "p_is_rows",
@@ -127,6 +134,12 @@ U_JUMP, U_MH, U_DIST, U_HOP0 = 0, 1, 2, 3
 # exercised by the benchmark anti-rot tier (benchmarks/run.py --smoke), so a
 # new layout cannot silently rot out of tier-1 coverage.
 LAYOUTS = ("sparse", "dense", "bucketed", "ragged")
+
+# Layouts whose Pallas kernel the TPU compiler (Mosaic) accepts.  The
+# sparse, bucketed and dense kernels take a cumsum, which Mosaic does not
+# lower, so on a TPU those layouts run the scan backend (XLA) under "auto";
+# see WalkEngine.resolved_backend.
+TPU_PALLAS_LAYOUTS = ("ragged",)
 
 # Environment override for backend="auto": set REPRO_BACKEND=scan|pallas to
 # pin the resolved backend (off-TPU the pallas backend runs interpret mode).
@@ -1057,12 +1070,34 @@ class WalkEngine:
 
     @property
     def resolved_backend(self) -> str:
-        if self.backend != "auto":
-            return self.backend
-        env = os.environ.get(BACKEND_ENV_VAR, "").strip().lower()
-        if env in ("scan", "pallas"):
-            return env
-        return "pallas" if jax.default_backend() == "tpu" else "scan"
+        """THE backend rule.  ``"auto"`` (unless :data:`BACKEND_ENV_VAR`
+        pins it) is ``"pallas"`` on TPU for the layouts in
+        :data:`TPU_PALLAS_LAYOUTS` and ``"scan"`` (XLA) everywhere else.
+        An explicit or pinned ``"pallas"`` on a TPU, on a layout whose
+        kernel the TPU compiler rejects, raises unless ``interpret`` was
+        asked for: a chip never drops to interpret mode silently."""
+        backend = self.backend
+        on_tpu = jax.default_backend() == "tpu"
+        if backend == "auto":
+            env = os.environ.get(BACKEND_ENV_VAR, "").strip().lower()
+            if env in ("scan", "pallas"):
+                backend = env
+            elif on_tpu and self.layout in TPU_PALLAS_LAYOUTS:
+                return "pallas"
+            else:
+                return "scan"
+        if (
+            backend == "pallas"
+            and on_tpu
+            and self.interpret is None
+            and self.layout not in TPU_PALLAS_LAYOUTS
+        ):
+            raise ValueError(
+                f"the {self.layout!r} layout's Pallas kernel does not compile "
+                f"for TPU (Mosaic has no cumsum lowering); use "
+                f"backend='scan' or 'auto', or layout='ragged'"
+            )
+        return backend
 
     @property
     def resolved_interpret(self) -> bool:
@@ -1342,6 +1377,23 @@ class WalkEngine:
             x, NamedSharding(s.mesh, spec)
         )
 
+    def _per_walker_shard(self, kernel):
+        """Run ``kernel(nodes, indptr, indices, edge_cdf, u)`` once per
+        device on that device's walkers (``shard_map`` over the walker
+        mesh axis; graph state replicated).  The TPU compiler does not
+        partition a Pallas kernel call by itself; it refuses one."""
+        from jax.sharding import PartitionSpec
+
+        s = self.walker_sharding
+        walk, repl = PartitionSpec(*s.spec[:1]), PartitionSpec()
+        return jax.shard_map(
+            kernel,
+            mesh=s.mesh,
+            in_specs=(walk, repl, repl, repl, walk),
+            out_specs=(walk, walk),
+            check_vma=False,
+        )
+
     # -- the transition -----------------------------------------------------
 
     def step(
@@ -1428,18 +1480,17 @@ class WalkEngine:
                     walk_transition_ragged,
                 )
 
-                nxt, hops = walk_transition_ragged(
-                    nodes,
-                    self.indptr,
-                    self.degrees,
-                    self.indices,
-                    self.edge_cdf,
-                    u,
+                kernel = functools.partial(
+                    walk_transition_ragged,
                     p_d=self.p_d,
                     r=self.r,
-                    max_degree=self.max_degree,
                     block_w=self.block_w,
                     interpret=self.resolved_interpret,
+                )
+                if self.walker_sharding is not None and not squeeze:
+                    kernel = self._per_walker_shard(kernel)
+                nxt, hops = kernel(
+                    nodes, self.indptr, self.indices, self.edge_cdf, u
                 )
             else:
                 v_mh = ragged_mh_invert(

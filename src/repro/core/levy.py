@@ -66,9 +66,10 @@ def trunc_geom_icdf(u, p_d: float, r: int):
 
 
 def trunc_geom_mean(p_d: float, r: int) -> float:
-    """E[D] for D ~ TruncGeom(p_d, r)."""
+    """E[D] for D ~ TruncGeom(p_d, r), as 1 + E[D - 1] so that rounding
+    never takes it below 1."""
     pmf = trunc_geom_pmf(p_d, r)
-    return float(np.dot(np.arange(1, r + 1), pmf))
+    return 1.0 + float(np.dot(np.arange(r), pmf))
 
 
 def levy_weights(p_d: float, r: int) -> np.ndarray:
@@ -108,7 +109,8 @@ def expected_transitions_per_update(p_j: float, p_d: float, r: int) -> float:
     Returns the exact value (1-p_J)*1 + p_J*E[D]; the paper's bound is
     1 + p_J(1/p_d - 1) and is asserted >= exact in tests.
     """
-    return (1.0 - p_j) * 1.0 + p_j * trunc_geom_mean(p_d, r)
+    # the same value as 1 + p_J*(E[D] - 1): never below 1 after rounding
+    return 1.0 + p_j * (trunc_geom_mean(p_d, r) - 1.0)
 
 
 def remark1_bound(p_j: float, p_d: float, r: int) -> float:

@@ -4,7 +4,7 @@ These back the ``"pallas"`` backend of :class:`repro.core.engine.WalkEngine`;
 the per-walk bodies mirror ``engine.mhlj_transition_math`` statement for
 statement, and the parity tests assert bitwise-equal outputs.
 
-Three entry points:
+Four entry points:
 
 * :func:`walk_transition` — the ``layout="dense"`` path: the full
   ``(n, max_deg)`` P_IS/neighbor tables live in VMEM and every per-walk row
@@ -23,18 +23,17 @@ Three entry points:
   O(max_deg) per low-degree walk; the CDF inversion itself still exists
   exactly once (``_sparse_kernel``).
 * :func:`walk_transition_ragged` — the ``layout="ragged"`` fused kernel:
-  a ``PrefetchScalarGridSpec`` launch whose scalar-prefetch arguments
-  (walk nodes, CSR ``indptr``, ``degrees``) drive per-walk ``pl.dslice``
-  loads straight out of the **flat** per-edge CDF/index buffers at each
-  row's *true* degree — no padded tile is ever gathered, no bucket ladder
-  dispatched.  The whole MHLJ step fuses into the one pass: the MH move
-  is a binary search of the walk's CDF segment (mirroring
-  ``engine.ragged_mh_invert``), the Lévy branch runs its r CSR-gathered
-  hops in-kernel, and the jump/MH combine writes ``(next, hops)``
-  directly — none of the O(W) XLA gather round-trips the other sparse
-  layouts leave between the tile kernel and ``engine.levy_jump_batched``.
+  a ``PrefetchScalarGridSpec`` launch that runs on the TPU's scalar core.
+  CSR ``indptr`` is scalar-prefetched into SMEM, each walk tile's nodes and
+  uniforms ride in SMEM blocks, and the **flat** per-edge CDF/index
+  buffers sit in VMEM, read one element at a time at each row's *true*
+  degree — no padded tile is ever gathered, no bucket ladder dispatched.
+  The whole MHLJ step fuses into the one pass: the MH move is a binary
+  search of the walk's CDF segment (mirroring ``engine.ragged_mh_invert``),
+  the Lévy branch runs its CSR-gathered hops in-kernel, and each walk
+  writes ``(next, hops)`` of its own branch directly.
 
-One grid step processes ``block_w`` walks.  Per walk:
+The dense kernel processes ``block_w`` walks per grid step.  Per walk:
   * MH-IS move: CDF inversion over the walk's padded P_IS neighbor row
     (precomputed or live (n, max_deg) table, resident in VMEM — graphs here
     are orchestration-scale, n <= a few thousand silos);
@@ -42,8 +41,13 @@ One grid step processes ``block_w`` walks.  Per walk:
     inverse CDF (``core.levy.trunc_geom_icdf``), then d uniform hops using
     the neighbors/degrees tables.
 
-All per-walk work is scalar loads from VMEM tables (pl.dslice rows +
-static-column picks) — no vector gathers, which keeps the kernel TPU-legal.
+What the TPU compiler accepts (compiled for a described v5e; see
+``tests/test_tpu_compile.py``): only the ragged kernel.  The dense and
+sparse kernels (and the bucketed dispatch, which reuses the sparse one)
+take a ``cumsum``, which Mosaic does not lower, so they run in interpret
+mode off-TPU only, and on a TPU their layouts run the scan backend (XLA);
+``engine.TPU_PALLAS_LAYOUTS`` and ``WalkEngine.resolved_backend`` hold
+that rule.
 
 When W is not a multiple of ``block_w`` the walk axis is padded up to the
 next block multiple and the padded lanes sliced off afterwards, so large
@@ -68,7 +72,6 @@ Outputs:
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +97,10 @@ __all__ = [
     "walk_transition_ragged",
 ]
 
+_LANES = 128  # lanes of one VMEM vector row
+_SMEM_TILE = 1024  # TPU tiling of a 1-D 32-bit array: compiled 1-D blocks
+#   are a multiple of this, or span the whole array
+
 
 def _kernel(
     nodes_ref, probs_ref, neigh_ref, deg_ref, u_ref, out_ref, hops_ref,
@@ -103,23 +110,23 @@ def _kernel(
         v = nodes_ref[w]
 
         # --- MH-IS move via CDF inversion over the padded neighbor row ----
-        prow = pl.load(probs_ref, (pl.dslice(v, 1), slice(None)))[0]  # (max_deg,)
+        prow = probs_ref[pl.dslice(v, 1), :][0]  # (max_deg,)
         cdf = jnp.cumsum(prow)
         idx = jnp.sum((cdf < u_ref[w, U_MH] * cdf[-1]).astype(jnp.int32))
         idx = jnp.minimum(idx, max_deg - 1)
-        nrow = pl.load(neigh_ref, (pl.dslice(v, 1), slice(None)))[0]
+        nrow = neigh_ref[pl.dslice(v, 1), :][0]
         v_mh = jnp.take(nrow, idx, axis=0)
 
         # --- Lévy jump: shared TruncGeom inverse CDF, then d uniform hops -
         d = trunc_geom_icdf(u_ref[w, U_DIST], p_d, r)
 
         def hop(i, v_cur):
-            deg = pl.load(deg_ref, (pl.dslice(v_cur, 1), slice(None)))[0, 0]
+            deg = deg_ref[pl.dslice(v_cur, 1), :][0, 0]
             hop_idx = jnp.minimum(
                 (u_ref[w, U_HOP0 + i] * deg.astype(jnp.float32)).astype(jnp.int32),
                 deg - 1,
             )
-            row = pl.load(neigh_ref, (pl.dslice(v_cur, 1), slice(None)))[0]
+            row = neigh_ref[pl.dslice(v_cur, 1), :][0]
             v_new = jnp.take(row, hop_idx, axis=0)
             return jnp.where(i < d, v_new, v_cur)
 
@@ -199,7 +206,8 @@ def _sparse_kernel(probs_ref, neigh_ref, u_ref, out_ref, *, block_w, max_deg):
     Same arithmetic as the per-walk body of ``mhlj_transition_math``
     (cumsum, ``cdf < u * cdf[-1]`` count, clamp) so outputs stay bitwise
     equal to the scan backend; the neighbor pick is a one-hot reduction
-    instead of a gather to stay TPU-legal.
+    instead of a gather.  The TPU compiler rejects the ``cumsum``, so this
+    kernel runs in interpret mode only (see the module docstring).
     """
     prow = probs_ref[...]  # (block_w, max_deg) f32
     cdf = jnp.cumsum(prow, axis=1)
@@ -331,161 +339,161 @@ def walk_transition_bucketed_compacted(
 
 
 def _ragged_kernel(
-    # scalar-prefetch refs (SMEM): available before the body runs, used to
-    # compute every flat-buffer address
-    nodes_ref,  # (W_pad,) int32 current node per walk
-    indptr_ref,  # (n+1,) int32 CSR row pointers
-    deg_ref,  # (n,) int32 true degrees
-    # tensor refs
-    cdf_ref,  # (nnz,) f32 flat per-edge CDF
-    idx_ref,  # (nnz,) int32 flat CSR neighbor ids
-    u_ref,  # (block_w, 3 + r) f32 uniforms tile
-    out_ref,  # (block_w,) int32
-    hops_ref,  # (block_w,) int32
+    indptr_ref,  # scalar prefetch (SMEM): (n+1,) int32 CSR row pointers
+    nodes_ref,  # (block_w,) int32 current node per walk (SMEM)
+    u_ref,  # (3 + r, block_w) f32 uniforms, walk-minor (SMEM); slot
+    #   U_DIST already holds the resolved jump distance d
+    cdf_ref,  # (rows, 128) f32 flat per-edge CDF, row-major (VMEM)
+    idx_ref,  # (rows, 128) int32 flat CSR neighbor ids, row-major (VMEM)
+    out_ref,  # (block_w,) int32 (SMEM)
+    hops_ref,  # (block_w,) int32 (SMEM)
     *,
-    p_d: float,
-    r: int,
     block_w: int,
-    search_iters: int,
 ):
-    i = pl.program_id(0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
 
     def load1(ref, at):
-        return pl.load(ref, (pl.dslice(at, 1),))[0]
+        # one element of a flat buffer: a dynamic-row vector load, then a
+        # one-hot lane reduction to a scalar (x + 0 is exact, so the value
+        # is bit for bit the stored one)
+        row = ref[pl.ds(at // _LANES, 1), :]
+        return jnp.sum(jnp.where(lane == at % _LANES, row, jnp.zeros_like(row)))
 
-    def one_walk(w, _):
-        v = nodes_ref[i * block_w + w]
+    def row_of(v):
         start = indptr_ref[v]
-        deg = deg_ref[v]
+        return start, indptr_ref[v + 1] - start
 
-        # --- MH-IS move: binary search of the row's true-degree CDF ------
-        # segment — mirrors engine.ragged_mh_invert statement for
-        # statement, so outputs stay bitwise-equal to every other layout
-        total = load1(cdf_ref, start + deg - 1)
-        t = u_ref[w, U_MH] * total
+    def one_walk(w, carry):
+        def mh_move(v):
+            # binary search of the row's true-degree CDF segment — the
+            # arithmetic of engine.ragged_mh_invert; a probe with lo >= hi
+            # changes nothing there, so stopping at lo == hi is the same
+            start, deg = row_of(v)
+            t = u_ref[U_MH, w] * load1(cdf_ref, start + deg - 1)
 
-        def probe(_, lohi):
-            lo, hi = lohi
-            active = lo < hi
-            mid = (lo + hi) // 2
-            c = load1(cdf_ref, start + jnp.minimum(mid, deg - 1))
-            pred = active & (c < t)
-            lo = jnp.where(pred, mid + 1, lo)
-            hi = jnp.where(active & ~pred, mid, hi)
-            return lo, hi
+            def probe(lohi):
+                lo, hi = lohi
+                mid = (lo + hi) // 2  # < hi <= deg: no clamp needed
+                below = load1(cdf_ref, start + mid) < t
+                return jnp.where(below, mid + 1, lo), jnp.where(below, hi, mid)
 
-        lo, _hi = jax.lax.fori_loop(
-            0, search_iters, probe, (jnp.int32(0), deg)
-        )
-        v_mh = load1(idx_ref, start + jnp.minimum(lo, deg - 1))
-
-        # --- Lévy jump: shared TruncGeom inverse CDF, then d uniform hops
-        # gathered straight from the flat CSR (the csr= arithmetic of
-        # engine.levy_jump_batched, fused in-kernel)
-        d = trunc_geom_icdf(u_ref[w, U_DIST], p_d, r)
-
-        def hop(j, v_cur):
-            deg_c = deg_ref[v_cur]
-            hop_idx = jnp.minimum(
-                (u_ref[w, U_HOP0 + j] * deg_c.astype(jnp.float32)).astype(
-                    jnp.int32
-                ),
-                deg_c - 1,
+            lo, _ = jax.lax.while_loop(
+                lambda lohi: lohi[0] < lohi[1], probe, (jnp.int32(0), deg)
             )
-            v_new = load1(idx_ref, indptr_ref[v_cur] + hop_idx)
-            return jnp.where(j < d, v_new, v_cur)
+            return load1(idx_ref, start + jnp.minimum(lo, deg - 1)), jnp.int32(1)
 
-        v_jump = jax.lax.fori_loop(0, r, hop, v)
+        def levy_jump(v):
+            # d uniform hops from the flat CSR — the csr= arithmetic of
+            # engine.levy_jump_batched
+            d = u_ref[U_DIST, w].astype(jnp.int32)
 
-        do_jump = u_ref[w, U_JUMP] > 0.5
-        out_ref[w] = jnp.where(do_jump, v_jump, v_mh)
-        hops_ref[w] = jnp.where(do_jump, d, jnp.int32(1))
-        return _
+            def hop(j, v_cur):
+                start, deg = row_of(v_cur)
+                k = jnp.minimum(
+                    (u_ref[U_HOP0 + j, w] * deg.astype(jnp.float32)).astype(
+                        jnp.int32
+                    ),
+                    deg - 1,
+                )
+                return load1(idx_ref, start + k)
+
+            return jax.lax.fori_loop(0, d, hop, v), d
+
+        nxt, hops = jax.lax.cond(
+            u_ref[U_JUMP, w] > 0.5, levy_jump, mh_move, nodes_ref[w]
+        )
+        out_ref[w] = nxt
+        hops_ref[w] = hops
+        return carry
 
     jax.lax.fori_loop(0, block_w, one_walk, 0)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("p_d", "r", "max_degree", "block_w", "interpret"),
+    jax.jit, static_argnames=("p_d", "r", "block_w", "interpret")
 )
 def walk_transition_ragged(
     nodes: jnp.ndarray,  # (W,) int32
     indptr: jnp.ndarray,  # (n+1,) int32 CSR row pointers
-    degrees: jnp.ndarray,  # (n,) int32
     indices: jnp.ndarray,  # (nnz,) int32 flat CSR neighbor ids
     edge_cdf: jnp.ndarray,  # (nnz,) float32 flat per-edge CDF
     uniforms: jnp.ndarray,  # (W, 3 + r) float32, slot 0 = jump flag
     *,
     p_d: float,
     r: int,
-    max_degree: int,
-    block_w: int = 256,
+    block_w: int = 1024,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The fused true-degree MHLJ step — one scalar-prefetch pass per tile.
+    """The fused true-degree MHLJ step — one scalar-core pass per walk tile.
 
-    ``PrefetchScalarGridSpec`` prefetches the walk nodes and the CSR
-    ``indptr``/``degrees`` so every per-walk address into the flat
-    ``edge_cdf``/``indices`` buffers is computable up front; each walk
-    then (1) binary-searches its own CDF segment at its *true* degree
-    (``ceil(log2(max_degree + 1))`` probes — the only per-walk row work,
-    vs O(max_deg) on the padded layouts), (2) runs the r-hop Lévy chain
-    from the flat CSR, and (3) resolves the jump/MH branch, all inside
-    the kernel.  Per-walk arithmetic mirrors ``engine.ragged_mh_invert``
-    + ``engine.levy_jump_batched(csr=)`` + ``engine.combine_mh_jump``
-    statement for statement, so outputs are bitwise-equal to every other
-    layout per key.  Working set is the flat O(E) buffers — no padded or
-    per-bucket table exists on this path, which is the point.
+    ``indptr`` is scalar-prefetched into SMEM, the walk tile's nodes and
+    uniforms ride in SMEM blocks, and the flat ``edge_cdf``/``indices``
+    buffers sit in VMEM as ``(rows, 128)`` arrays.  Each walk takes only
+    its own branch: an MH move binary-searches its CDF segment at its
+    *true* degree (at most ``ceil(log2(deg + 1))`` probes), a jump runs its
+    d hops from the flat CSR.  Per-walk arithmetic mirrors
+    ``engine.ragged_mh_invert`` + ``engine.levy_jump_batched(csr=)`` +
+    ``engine.combine_mh_jump``, so outputs are bitwise-equal to every
+    other layout per key.  The jump distance d is resolved here, outside
+    the kernel, by the same :func:`trunc_geom_icdf` XLA computation the
+    scan backend runs, so the ``log1p`` never has to agree across
+    compilers.
 
-    On-hardware caveat (ROADMAP): the flat buffers ride in kernel memory
-    whole, like the dense kernel's tables — real-TPU runs at nnz beyond
-    VMEM need an HBM + DMA variant; CI exercises interpret mode.
+    Capacity on a TPU v5e: ``indptr`` must fit SMEM (1 MiB, so n up to
+    about 200k), and both flat buffers must fit the scoped VMEM together
+    (16 MiB by default, so nnz up to about 2M).  Larger graphs need an
+    HBM + DMA variant.  Compiled walk tiles are a multiple of 1024 walks
+    (the TPU tiling of a 1-D 32-bit array) unless one tile holds all W.
 
     Returns ``(next_nodes, hops)``, both (W,) int32.
     """
     w = nodes.shape[0]
-    n_u = num_uniforms(r)
     bw = min(block_w, w)
+    if not interpret and bw < w:
+        bw = min(-(-bw // _SMEM_TILE) * _SMEM_TILE, w)
     w_pad = -(-w // bw) * bw
+    d = trunc_geom_icdf(uniforms[:, U_DIST], p_d, r)
+    uniforms = uniforms.at[:, U_DIST].set(d.astype(jnp.float32))
     if w_pad != w:
-        # padded lanes walk node 0 on zero uniforms and are sliced off below
+        # padded lanes make an MH move on node 0 and are sliced off below
         nodes = jnp.pad(nodes, (0, w_pad - w))
         uniforms = jnp.pad(uniforms, ((0, w_pad - w), (0, 0)))
-    search_iters = max(1, math.ceil(math.log2(max_degree + 1)))
+    nnz = edge_cdf.shape[0]
+    rows = -(-nnz // _SMEM_TILE) * (_SMEM_TILE // _LANES)
+
+    def flat_rows(x):
+        return jnp.pad(x, (0, rows * _LANES - nnz)).reshape(rows, _LANES)
+
+    tile = pl.BlockSpec((bw,), lambda i, *_: (i,), memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # nodes, indptr, degrees
+        num_scalar_prefetch=1,  # indptr
         grid=(w_pad // bw,),
         in_specs=[
-            pl.BlockSpec(edge_cdf.shape, lambda i, *_: (0,)),
-            pl.BlockSpec(indices.shape, lambda i, *_: (0,)),
-            pl.BlockSpec((bw, n_u), lambda i, *_: (i, 0)),
+            tile,
+            pl.BlockSpec(
+                (num_uniforms(r), bw),
+                lambda i, *_: (0, i),
+                memory_space=pltpu.SMEM,
+            ),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
-        out_specs=[
-            pl.BlockSpec((bw,), lambda i, *_: (i,)),
-            pl.BlockSpec((bw,), lambda i, *_: (i,)),
-        ],
+        out_specs=[tile, tile],
     )
     next_nodes, hops = pl.pallas_call(
-        functools.partial(
-            _ragged_kernel,
-            p_d=p_d,
-            r=r,
-            block_w=bw,
-            search_iters=search_iters,
-        ),
+        functools.partial(_ragged_kernel, block_w=bw),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((w_pad,), jnp.int32),
             jax.ShapeDtypeStruct((w_pad,), jnp.int32),
         ],
         interpret=interpret,
+        name="walk_transition_ragged",
     )(
-        nodes.astype(jnp.int32),
         indptr.astype(jnp.int32),
-        degrees.astype(jnp.int32),
-        edge_cdf,
-        indices.astype(jnp.int32),
-        uniforms,
+        nodes.astype(jnp.int32),
+        uniforms.T,
+        flat_rows(edge_cdf),
+        flat_rows(indices.astype(jnp.int32)),
     )
     return next_nodes[:w], hops[:w]

@@ -123,7 +123,7 @@ def _lower_case_inner(
         rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
         out_sh = (in_sh[0], in_sh[1], in_sh[2], rep)
         fn = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh, donate_argnums=(0, 1))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(params_shapes, opt_shapes, walk_shapes, batch_shapes)
     elif shape.kind == "prefill":
         profile = _decode_profile(cfg)
@@ -141,7 +141,7 @@ def _lower_case_inner(
         b_spec = sh.batch_specs(batch_shapes, profile, mesh)
         in_sh = tuple(sh.named_shardings(s, mesh) for s in (p_spec, b_spec))
         fn = jax.jit(prefill_step, in_shardings=in_sh)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(params_shapes, batch_shapes)
     else:  # decode
         profile = _decode_profile(cfg)
@@ -163,7 +163,7 @@ def _lower_case_inner(
         out_sh = (in_sh[2], in_sh[1])
         fn = jax.jit(serve, in_shardings=in_sh, out_shardings=out_sh, donate_argnums=(1,))
         pos_shape = jax.ShapeDtypeStruct((), jnp.int32)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(params_shapes, cache_shapes, tok_shapes, pos_shape)
 
     t0 = time.time()

@@ -9,8 +9,17 @@ jax device state (the dry-run sets XLA_FLAGS before any jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_smoke_mesh", "make_walker_mesh", "HW"]
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: shardings are propagated by
+    the compiler and ``with_sharding_constraint`` takes plain specs (JAX's
+    default is now ``Explicit`` axes, which the constraints here do not
+    use)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, model_parallel: int = 16):
@@ -21,12 +30,12 @@ def make_production_mesh(*, multi_pod: bool = False, model_parallel: int = 16):
     data = 256 // model_parallel
     shape = (2, data, model_parallel) if multi_pod else (data, model_parallel)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """1-device mesh for CPU smoke tests (same axis names as production)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_walker_mesh(num_devices: int | None = None):
@@ -38,7 +47,7 @@ def make_walker_mesh(num_devices: int | None = None):
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before jax
     initializes to get a multi-device fleet mesh (the CI sharded leg)."""
     n = len(jax.devices()) if num_devices is None else num_devices
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 class HW:

@@ -55,7 +55,10 @@ __all__ = [
     "Request",
     "ServeEngine",
     "ServeSimulator",
+    "build_engine",
+    "build_parser",
     "build_route_engine",
+    "build_simulator",
     "latency_percentiles",
     "load_arrival_trace",
     "save_arrival_trace",
@@ -755,7 +758,8 @@ class ServeSimulator:
         }
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI's arguments (``main`` and ``chip_smoke.py`` share it)."""
     from repro.walk_sgd.trainer import METHODS
 
     ap = argparse.ArgumentParser(description=__doc__)
@@ -807,12 +811,58 @@ def main():
                     "requests to the slot engine (the original demo)")
     ap.add_argument("--requests", type=int, default=8,
                     help="standalone mode: number of direct-submitted requests")
-    args = ap.parse_args()
+    return ap
 
+
+def build_engine(args) -> ServeEngine:
+    """The slot engine for parsed CLI ``args`` (``--scale full`` = the
+    architecture at its published widths, ``smoke`` = ``reduced()``)."""
     cfg = reduced(get_arch(args.arch)) if args.scale == "smoke" else get_arch(args.arch)
-    engine = ServeEngine(
+    return ServeEngine(
         cfg, args.batch, args.cache_len, seed=args.seed, max_queue=args.max_queue
     )
+
+
+def build_simulator(args, engine: ServeEngine, graph=None) -> ServeSimulator:
+    """The walk-routed simulator for parsed CLI ``args``; ``graph``
+    defaults to the ragged Barabasi-Albert graph the arguments describe."""
+    if graph is None:
+        graph = barabasi_albert(
+            args.nodes, args.ba_m, seed=args.seed, layout="ragged"
+        )
+    fault_model = None
+    if args.crash_rate > 0.0:
+        fault_model = FaultModel(
+            crash_rate=args.crash_rate,
+            recovery_rate=args.recovery_rate,
+            patience=args.patience,
+            rescue=not args.no_rescue,
+        )
+    return ServeSimulator(
+        graph,
+        engine,
+        method=args.method,
+        num_walkers=args.walkers,
+        rate=args.rate,
+        pickup=args.pickup,
+        deadline_ticks=args.deadline,
+        max_new_tokens=args.max_new,
+        seed=args.seed,
+        fault_model=fault_model,
+        relocate_after=args.relocate_after,
+        arrival_trace=(
+            load_arrival_trace(args.trace) if args.trace else None
+        ),
+    )
+
+
+def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
+    engine = build_engine(args)
+    cfg = engine.cfg
 
     if args.standalone:
         rng = np.random.default_rng(args.seed)
@@ -830,31 +880,7 @@ def main():
             print(f"{k}: {v:.4g}" if isinstance(v, float) else f"{k}: {v}")
         return 0 if stats["completed"] == args.requests else 1
 
-    graph = barabasi_albert(args.nodes, args.ba_m, seed=args.seed, layout="ragged")
-    fault_model = None
-    if args.crash_rate > 0.0:
-        fault_model = FaultModel(
-            crash_rate=args.crash_rate,
-            recovery_rate=args.recovery_rate,
-            patience=args.patience,
-            rescue=not args.no_rescue,
-        )
-    sim = ServeSimulator(
-        graph,
-        engine,
-        method=args.method,
-        num_walkers=args.walkers,
-        rate=args.rate,
-        pickup=args.pickup,
-        deadline_ticks=args.deadline,
-        max_new_tokens=args.max_new,
-        seed=args.seed,
-        fault_model=fault_model,
-        relocate_after=args.relocate_after,
-        arrival_trace=(
-            load_arrival_trace(args.trace) if args.trace else None
-        ),
-    )
+    sim = build_simulator(args, engine)
     metrics = sim.run(args.ticks, drain_ticks=args.drain)
     if args.record_trace:
         save_arrival_trace(args.record_trace, sim.arrival_log)

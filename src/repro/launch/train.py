@@ -181,6 +181,9 @@ def _custom_cfg(args):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="qwen2.5-32b", choices=sorted(ARCHITECTURES))
     ap.add_argument("--scale", default="smoke", choices=["smoke", "custom", "full"])

@@ -47,28 +47,14 @@ class AttnDims:
 
 
 def _maybe_constrain(x: jnp.ndarray, spec: tuple) -> jnp.ndarray:
-    """with_sharding_constraint when a mesh with these axes is active (the
-    production lowering path); a no-op for un-meshed CPU tests.  Axes are
-    kept when the GSPMD padding waste ceil(dim/axis)*axis/dim is <= 2x —
+    """with_sharding_constraint when a mesh with these axes is set
+    (``jax.set_mesh``, the production lowering path); a no-op without
+    one.  Axes are kept when the GSPMD padding waste
+    ceil(dim/axis)*axis/dim is <= 2x —
     so 8 heads still shard over 16 devices (2x padding beats full batch
     replication, measured on paligemma prefill), but a batch-1 decode
     tensor is never forced onto a 16-way axis (measured regression)."""
-    axis_sizes = {}
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and mesh.shape_tuple:
-            axis_sizes = dict(mesh.shape_tuple)
-    except Exception:
-        pass
-    if not axis_sizes:  # legacy `with mesh:` context (thread resources)
-        try:
-            from jax._src import mesh as _mesh_lib
-
-            phys = _mesh_lib.thread_resources.env.physical_mesh
-            if not phys.empty:
-                axis_sizes = dict(zip(phys.axis_names, phys.devices.shape))
-        except Exception:
-            pass
+    axis_sizes = dict(jax.sharding.get_abstract_mesh().shape_tuple)
     if not axis_sizes:
         return x
     def keep(i, s):
@@ -79,10 +65,7 @@ def _maybe_constrain(x: jnp.ndarray, spec: tuple) -> jnp.ndarray:
         return padded <= 2 * dim
 
     used = tuple(s if keep(i, s) else None for i, s in enumerate(spec))
-    try:
-        return jax.lax.with_sharding_constraint(x, jax.sharding.PartitionSpec(*used))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, jax.sharding.PartitionSpec(*used))
 
 
 def attn_init(key, dims: AttnDims, dtype=jnp.bfloat16) -> dict:

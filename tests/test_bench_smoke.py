@@ -22,8 +22,11 @@ from repro.core.engine import LAYOUTS
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _env():
+def _env(cache_dir):
     env = dict(os.environ)
+    # benchmarks.run keeps its compile cache where this variable says; a
+    # per-test directory keeps the checkout clean
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     env["PYTHONPATH"] = os.path.join(REPO, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
@@ -35,7 +38,7 @@ def test_benchmarks_smoke_tier_passes(tmp_path):
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.run", "--smoke", "--json", json_path],
         cwd=REPO,
-        env=_env(),
+        env=_env(tmp_path / "jax_cache"),
         capture_output=True,
         text=True,
         timeout=540,
@@ -115,7 +118,7 @@ def test_benchmarks_smoke_tier_passes(tmp_path):
             "--fresh", json_path,
         ],
         cwd=REPO,
-        env=_env(),
+        env=_env(tmp_path / "jax_cache"),
         capture_output=True,
         text=True,
         timeout=120,

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core.graphs import ring
 from repro.core.transition import MHLJParams
@@ -37,28 +38,16 @@ def test_maybe_constrain_noop_without_mesh():
     np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def _activate_mesh(mesh):
-    """Version-appropriate mesh activation: ``jax.set_mesh`` /
-    ``jax.sharding.set_mesh`` on new JAX, the legacy ``with mesh:`` context
-    (thread resources) on older releases."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is None:
-        set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on older JAX
-
-
 def test_maybe_constrain_skips_indivisible_dims():
     """Under a real mesh, dims that don't divide the axis are dropped (the
     batch-1 decode regression guard) — values unchanged either way."""
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
 
     @jax.jit
     def f(x):
         return A._maybe_constrain(x, ("model", None)) * 2.0
 
-    with _activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = f(jnp.ones((3, 4)))  # 3 % 1 == 0 -> constrained fine
     np.testing.assert_allclose(np.asarray(out), 2 * np.ones((3, 4)))
 

@@ -647,8 +647,8 @@ def test_ragged_kernel_oracle_parity():
     u = jax.random.uniform(jax.random.PRNGKey(5), (w, 3 + r))
     u = u.at[:, 0].set((u[:, 0] < 0.3).astype(jnp.float32))
     got = walk_transition_ragged(
-        nodes, indptr, degrees, indices, edge_cdf, u,
-        p_d=p_d, r=r, max_degree=csr.max_degree, block_w=16, interpret=True,
+        nodes, indptr, indices, edge_cdf, u,
+        p_d=p_d, r=r, block_w=16, interpret=True,
     )
     want = walk_transition_ragged_ref(
         nodes, indptr, degrees, indices, edge_cdf, u,
