@@ -1,5 +1,5 @@
 """Benchmark harness (deliverable d): one module per paper figure/claim plus
-the roofline and system benchmarks.
+the system benchmarks.
 
   PYTHONPATH=src python -m benchmarks.run [--quick] [--smoke] [--only fig3_ring,...]
 
@@ -35,7 +35,6 @@ from benchmarks import (
     law_sweep,
     llm_walk_throughput,
     multi_walk,
-    roofline,
     serve_throughput,
     theorem1_remark1,
 )
@@ -53,7 +52,6 @@ MODULES = [
     law_sweep,
     serve_throughput,
     fault_sweep,
-    roofline,
 ]
 
 
@@ -133,10 +131,6 @@ def main() -> int:
                 continue
             dump(mod.NAME, result)
             print(row(mod.NAME, seconds, derived))
-            if mod is roofline and "rows" in result:
-                print()
-                print(roofline.format_table(result["rows"]))
-                print()
         except Exception as e:
             failures += 1
             print(f"{mod.NAME},0,FAILED: {type(e).__name__}: {e}")

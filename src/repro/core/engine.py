@@ -89,10 +89,12 @@ walk (1 for an MH move, d for a Lévy jump).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
 import os
+import time
 from typing import Optional, Tuple, Union
 
 import jax
@@ -125,6 +127,9 @@ __all__ = [
     "combine_mh_jump",
     "levy_jump_batched",
     "WalkEngine",
+    "WALK_TRANSITION_SCOPE",
+    "EDGE_CDF_BUILD_SPAN",
+    "span_event",
 ]
 
 # Uniform-block slot layout (shared with the Pallas kernel).
@@ -145,6 +150,37 @@ TPU_PALLAS_LAYOUTS = ("ragged",)
 # pin the resolved backend (off-TPU the pallas backend runs interpret mode).
 # This is what the CI matrix flips to run tier-1 under both backends.
 BACKEND_ENV_VAR = "REPRO_BACKEND"
+
+# Names the program gives its layers.  The device scope covers one MHLJ
+# transition of every layout and backend (``jax.named_scope``: HLO metadata
+# only, so a profile can attribute device time to it).  The host span covers
+# the flat CDF build of the ragged layout's set-up.
+WALK_TRANSITION_SCOPE = "walk_transition"
+EDGE_CDF_BUILD_SPAN = "edge_cdf_build"
+
+
+def span_event(span: str, counter: str = "seconds") -> str:
+    """The ``jax.monitoring`` event of a host span: ``seconds`` carries its
+    duration, any other counter is one event per occurrence inside it."""
+    return f"/repro/{span}/{counter}"
+
+
+# Seconds spent in each host span since the process started, for a reader
+# that was not listening when the span ran (a benchmark's set-up metric).
+span_seconds: dict = {}
+
+
+@contextlib.contextmanager
+def _host_span(name: str):
+    """A profiler span (``jax.profiler.TraceAnnotation``) whose duration is
+    also recorded through ``jax.monitoring`` and added to ``span_seconds``;
+    with neither a profiler nor a listener it costs a clock read."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(name):
+        yield
+    seconds = time.perf_counter() - t0
+    span_seconds[name] = span_seconds.get(name, 0.0) + seconds
+    jax.monitoring.record_event_duration_secs(span_event(name), seconds)
 
 
 def num_uniforms(r: int) -> int:
@@ -227,6 +263,7 @@ def mh_cdf_invert(
     return jnp.take_along_axis(neigh_rows, idx[:, None], axis=1)[:, 0]
 
 
+@_host_span(EDGE_CDF_BUILD_SPAN)
 def ragged_edge_cdf(
     indptr,
     indices,
@@ -309,6 +346,7 @@ def ragged_edge_cdf(
     out = np.empty(nnz, dtype=np.float32)
     cols = np.arange(width)
     for ids in _ragged_row_chunks(n, width, chunk_rows):
+        jax.monitoring.record_event(span_event(EDGE_CDF_BUILD_SPAN, "chunks"))
         if flat_probs is not None:
             rows = np.zeros((ids.size, width), dtype=np.float32)
             mask = cols[None, :] < deg_np[ids][:, None]
@@ -1453,6 +1491,47 @@ class WalkEngine:
         squeeze = nodes.ndim == 0
         if squeeze:
             nodes = nodes[None]
+        with jax.named_scope(WALK_TRANSITION_SCOPE):
+            nxt, hops, overflow = self._transition(
+                key, nodes, p_j, lipschitz, squeeze
+            )
+        aux = {"compact_overflow": overflow}
+        if faults is not None:
+            # liveness masking applies AFTER the backend dispatch, on the
+            # proposed endpoints — every backend/layout pair shares this
+            # exact rejection + rescue arithmetic (see docs/faults.md)
+            fmodel, fstate = faults
+            nxt, hops, blocked, was_blocked, rescued = faults_mod.apply_liveness(
+                rescue_key,
+                nodes,
+                nxt,
+                hops,
+                jnp.atleast_1d(fstate.blocked),
+                fmodel.live_mask(fstate),
+                patience=fmodel.patience,
+                rescue=fmodel.rescue,
+                rescue_hops=self.r,
+                edge_live=fmodel.edge_live_mask(fstate),
+                indptr=self.indptr,
+                indices=self.indices,
+                max_degree=self.max_degree,
+            )
+            aux["blocked_steps"] = blocked[0] if squeeze else blocked
+            aux["fault_blocked"] = was_blocked[0] if squeeze else was_blocked
+            aux["rescued"] = rescued[0] if squeeze else rescued
+        if self.walker_sharding is not None and not squeeze:
+            nxt = self._constrain_walkers(nxt)
+            hops = self._constrain_walkers(hops)
+        if squeeze:
+            nxt, hops = nxt[0], hops[0]
+        if with_aux:
+            return nxt, hops, aux
+        return nxt, hops
+
+    def _transition(self, key, nodes, p_j, lipschitz, squeeze):
+        """The proposal of :meth:`step`: the uniform draw, the layout's
+        backend dispatch and the combine, for (W,) ``nodes``.  Returns
+        ``(next_nodes, hops, compact_overflow)``."""
         p_j_t = self.p_j if p_j is None else p_j
         u = jax.random.uniform(
             key, (nodes.shape[0], num_uniforms(self.r)), jnp.float32
@@ -1563,38 +1642,7 @@ class WalkEngine:
                 self.p_d,
                 self.r,
             )
-        aux = {"compact_overflow": overflow}
-        if faults is not None:
-            # liveness masking applies AFTER the backend dispatch, on the
-            # proposed endpoints — every backend/layout pair shares this
-            # exact rejection + rescue arithmetic (see docs/faults.md)
-            fmodel, fstate = faults
-            nxt, hops, blocked, was_blocked, rescued = faults_mod.apply_liveness(
-                rescue_key,
-                nodes,
-                nxt,
-                hops,
-                jnp.atleast_1d(fstate.blocked),
-                fmodel.live_mask(fstate),
-                patience=fmodel.patience,
-                rescue=fmodel.rescue,
-                rescue_hops=self.r,
-                edge_live=fmodel.edge_live_mask(fstate),
-                indptr=self.indptr,
-                indices=self.indices,
-                max_degree=self.max_degree,
-            )
-            aux["blocked_steps"] = blocked[0] if squeeze else blocked
-            aux["fault_blocked"] = was_blocked[0] if squeeze else was_blocked
-            aux["rescued"] = rescued[0] if squeeze else rescued
-        if self.walker_sharding is not None and not squeeze:
-            nxt = self._constrain_walkers(nxt)
-            hops = self._constrain_walkers(hops)
-        if squeeze:
-            nxt, hops = nxt[0], hops[0]
-        if with_aux:
-            return nxt, hops, aux
-        return nxt, hops
+        return nxt, hops, overflow
 
     def run(
         self,

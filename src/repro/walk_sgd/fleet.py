@@ -467,6 +467,14 @@ def _fleet_unflatten(aux, children) -> WalkFleet:
 jax.tree_util.register_pytree_node(WalkFleet, _fleet_flatten, _fleet_unflatten)
 
 
+# Device scopes of the fleet step (``jax.named_scope``: HLO metadata only),
+# beside the walk's own ``engine.WALK_TRANSITION_SCOPE``: the per-walker SGD
+# update with its row gathers, the model average, and the loss evaluation.
+FLEET_SGD_SCOPE = "fleet_sgd"
+FLEET_AVERAGE_SCOPE = "fleet_average"
+FLEET_LOSS_EVAL_SCOPE = "fleet_loss_eval"
+
+
 # ---------------------------------------------------------------------------
 # THE fleet training scan (regression path): the single implementation that
 # replaced trainer._run_scan (its W=1 case) and trainer._run_scan_multi.
@@ -517,30 +525,32 @@ def _fleet_scan(
             key_t, key_f = jax.random.split(key_t)
             fstate = fmodel.advance(key_f, fstate)
             alive_w = fmodel.live_mask(fstate)[vs]  # (W,) walker liveness
-        gs = grad_w(xs, features[vs], targets[vs])  # (W, dim)
-        ws = jnp.where(use_weights, weights[vs], 1.0)[:, None]
-        xs_new = xs - gamma * ws * gs
-        if alive_w is not None:
-            xs_new = jnp.where(alive_w[:, None], xs_new, xs)
+        with jax.named_scope(FLEET_SGD_SCOPE):
+            gs = grad_w(xs, features[vs], targets[vs])  # (W, dim)
+            ws = jnp.where(use_weights, weights[vs], 1.0)[:, None]
+            xs_new = xs - gamma * ws * gs
+            if alive_w is not None:
+                xs_new = jnp.where(alive_w[:, None], xs_new, xs)
         if avg_every > 0:
-            do_avg = (t + 1) % avg_every == 0
-            if alive_w is None:
-                xs_new = fleet_average(xs_new, do_avg)
-            else:
-                # dead walkers are unreachable: they neither contribute to
-                # nor receive the average (a parked model stays frozen and
-                # drags the fleet only when it REJOINS — the stalled-worker
-                # cost benchmarks/fault_sweep.py measures)
-                w_live = alive_w.astype(xs_new.dtype)[:, None]
-                mean = (xs_new * w_live).sum(axis=0, keepdims=True) / (
-                    jnp.maximum(w_live.sum(), 1.0)
-                )
-                avg = jnp.broadcast_to(mean, xs_new.shape).astype(
-                    xs_new.dtype
-                )
-                xs_new = jnp.where(
-                    do_avg & alive_w[:, None], avg, xs_new
-                )
+            with jax.named_scope(FLEET_AVERAGE_SCOPE):
+                do_avg = (t + 1) % avg_every == 0
+                if alive_w is None:
+                    xs_new = fleet_average(xs_new, do_avg)
+                else:
+                    # dead walkers are unreachable: they neither contribute to
+                    # nor receive the average (a parked model stays frozen and
+                    # drags the fleet only when it REJOINS — the stalled-worker
+                    # cost benchmarks/fault_sweep.py measures)
+                    w_live = alive_w.astype(xs_new.dtype)[:, None]
+                    mean = (xs_new * w_live).sum(axis=0, keepdims=True) / (
+                        jnp.maximum(w_live.sum(), 1.0)
+                    )
+                    avg = jnp.broadcast_to(mean, xs_new.shape).astype(
+                        xs_new.dtype
+                    )
+                    xs_new = jnp.where(
+                        do_avg & alive_w[:, None], avg, xs_new
+                    )
         if faults is None:
             vs_next, hops = engine.step(key_t, vs, p_j=p_j_t)  # ONE batched call
         else:
@@ -550,10 +560,11 @@ def _fleet_scan(
             fstate = dataclasses.replace(
                 fstate, blocked=aux["blocked_steps"]
             )
-        mses = jax.vmap(reg.mse_objective, in_axes=(0, None, None))(
-            xs_new, features, targets
-        )
-        avg_mse = reg.mse_objective(xs_new.mean(axis=0), features, targets)
+        with jax.named_scope(FLEET_LOSS_EVAL_SCOPE):
+            mses = jax.vmap(reg.mse_objective, in_axes=(0, None, None))(
+                xs_new, features, targets
+            )
+            avg_mse = reg.mse_objective(xs_new.mean(axis=0), features, targets)
         if faults is None:
             return (xs_new, vs_next, t + 1), (mses, avg_mse, vs, hops)
         return (
@@ -581,10 +592,11 @@ def _fleet_scan(
         )
         final = {"nodes": vs_fin, "fault_state": fstate_fin,
                  "rescued": rescued, "blocked": blocked}
-    mse0 = jax.vmap(reg.mse_objective, in_axes=(0, None, None))(
-        x0s, features, targets
-    )
-    avg0 = reg.mse_objective(x0s.mean(axis=0), features, targets)
+    with jax.named_scope(FLEET_LOSS_EVAL_SCOPE):
+        mse0 = jax.vmap(reg.mse_objective, in_axes=(0, None, None))(
+            x0s, features, targets
+        )
+        avg0 = reg.mse_objective(x0s.mean(axis=0), features, targets)
     return (
         xs_fin,
         jnp.concatenate([mse0[None], mses]).T,  # (W, T+1)
