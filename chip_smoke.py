@@ -11,8 +11,9 @@ All data comes from ``--seed``.  The graph is the repo's full-scale one,
 edges, max degree about 1.2k).
 
 * A, walk engine: W=8192 walkers, 64 steps, compiled Pallas kernel against
-  the scan backend; nodes and hops must agree bit for bit, and every hop
-  count must lie in Remark 1's range [1, r].
+  the scan backend; nodes and hops must agree bit for bit, every hop
+  count must lie in Remark 1's range [1, r], and ``backend="auto"`` must
+  resolve to scan (the kernel runs only when asked for).
 * B, fleet trainer: ``run_rw_sgd_multi("mhlj", ...)`` with W=8192,
   ``avg_every=4`` on the chip, and the same call on the host CPU with the
   scan backend.  The walk streams must agree bit for bit, ``avg_mse`` must
@@ -27,7 +28,8 @@ edges, max degree about 1.2k).
 * --four-chips: phase B's call on a 4-chip walker mesh against one device.
   The walk streams must agree bit for bit and ``x_final`` as closely as
   ``tests/test_fleet.py`` asks, the loss curves within ``B_MSE_RTOL``, and
-  the kernel must run once per device on W/4 walkers.
+  with ``backend="pallas"`` the kernel must run once per device on W/4
+  walkers.
 
 One JSON line per phase, then the compile cache, then as the last line
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
@@ -187,8 +189,9 @@ def phase_b_four_chips(graph, seed: int, *, walkers=B_WALKERS, steps=B_STEPS):
     single, single_s = _train(graph, data, engine, gamma, seed, walkers, steps)
     node_mm, hop_mm = _stream_mismatches(sharded, single)
 
-    # the kernel must run once per device on its own W/4 walkers
-    fleet = shard_fleet(WalkFleet.create(engine, walkers, seed=seed), mesh)
+    # asked for, the kernel must run once per device on its own W/4 walkers
+    pinned = dataclasses.replace(engine, backend="pallas", interpret=False)
+    fleet = shard_fleet(WalkFleet.create(pinned, walkers, seed=seed), mesh)
     text = (
         jax.jit(lambda f, k: f.engine.step(k, f.nodes))
         .lower(fleet, jax.random.PRNGKey(seed)).compile().as_text()
@@ -286,7 +289,7 @@ def main(argv=None) -> int:
         phase_b_four_chips(graph, args.seed)
     else:
         auto = phase_a(graph, args.seed)
-        check(auto == "pallas", f"'auto' resolved to {auto!r} for the ragged layout")
+        check(auto == "scan", f"'auto' resolved to {auto!r} for the ragged layout")
         phase_b(graph, args.seed)
         phase_c(graph, args.seed)
     emit("compile_cache", dir=cache_dir, entries=cache_entries(cache_dir))

@@ -60,13 +60,17 @@ Backends (identical law, bitwise-identical outputs given the same key):
   original full-table-in-VMEM kernel for parity testing at orchestration
   scale (n <= a few thousand).  The registered layouts live in
   :data:`LAYOUTS`.
-* ``"auto"``   — pallas on TPU for the layouts whose kernel the TPU
-  compiler accepts (:data:`TPU_PALLAS_LAYOUTS`: ragged only — the sparse,
-  bucketed and dense kernels take a ``cumsum`` Mosaic does not lower),
-  scan (XLA) for the others and everywhere off-TPU; an explicit
-  ``"pallas"`` on a rejected layout raises on TPU instead of dropping to
-  interpret mode (:attr:`WalkEngine.resolved_backend`).  Overridable via
-  the ``REPRO_BACKEND`` environment variable (:data:`BACKEND_ENV_VAR`),
+* ``"auto"``   — scan (XLA) on every layout, TPU included.  The chip
+  chose it: on a TPU v5e the ragged layout's W-wide XLA gathers beat its
+  one-walk-at-a-time scalar-core kernel in every cell of ``chipbench/``
+  (the kernel had been 92-99.95% of device time; ``PERF_LEDGER.jsonl``
+  and ``PERF.md`` §6 hold the numbers).  :data:`TPU_PALLAS_LAYOUTS` lists
+  the layouts whose kernel the TPU compiler accepts (ragged only — the
+  sparse, bucketed and dense kernels take a ``cumsum`` Mosaic does not
+  lower), so an explicit ``"pallas"`` runs compiled there and raises on
+  the others instead of dropping to interpret mode
+  (:attr:`WalkEngine.resolved_backend`).  Overridable via the
+  ``REPRO_BACKEND`` environment variable (:data:`BACKEND_ENV_VAR`),
   which is how the CI matrix forces each backend.  The scan backend also
   services the bucketed layout (pure-jnp per-bucket dispatch, compacted
   the same way), so the bucketed path runs everywhere the engine runs.
@@ -140,10 +144,12 @@ U_JUMP, U_MH, U_DIST, U_HOP0 = 0, 1, 2, 3
 # new layout cannot silently rot out of tier-1 coverage.
 LAYOUTS = ("sparse", "dense", "bucketed", "ragged")
 
-# Layouts whose Pallas kernel the TPU compiler (Mosaic) accepts.  The
-# sparse, bucketed and dense kernels take a cumsum, which Mosaic does not
-# lower, so on a TPU those layouts run the scan backend (XLA) under "auto";
-# see WalkEngine.resolved_backend.
+# Layouts whose Pallas kernel the TPU compiler (Mosaic) accepts, so where
+# an explicit backend="pallas" runs compiled on a TPU.  The sparse, bucketed
+# and dense kernels take a cumsum, which Mosaic does not lower.  "auto" runs
+# the scan backend (XLA) on every layout: measured on a TPU v5e, the ragged
+# kernel lost to the scan path's W-wide gathers in every chipbench cell
+# (PERF_LEDGER.jsonl, PERF.md §6); see WalkEngine.resolved_backend.
 TPU_PALLAS_LAYOUTS = ("ragged",)
 
 # Environment override for backend="auto": set REPRO_BACKEND=scan|pallas to
@@ -160,8 +166,10 @@ EDGE_CDF_BUILD_SPAN = "edge_cdf_build"
 
 
 def span_event(span: str, counter: str = "seconds") -> str:
-    """The ``jax.monitoring`` event of a host span: ``seconds`` carries its
-    duration, any other counter is one event per occurrence inside it."""
+    """The ``jax.monitoring`` event of a host span or device scope:
+    ``seconds`` carries a span's duration, any other counter is one event
+    per occurrence inside it (for the ``walk_transition`` scope, one
+    ``backend_<backend>`` event each time a transition is traced)."""
     return f"/repro/{span}/{counter}"
 
 
@@ -1109,24 +1117,22 @@ class WalkEngine:
     @property
     def resolved_backend(self) -> str:
         """THE backend rule.  ``"auto"`` (unless :data:`BACKEND_ENV_VAR`
-        pins it) is ``"pallas"`` on TPU for the layouts in
-        :data:`TPU_PALLAS_LAYOUTS` and ``"scan"`` (XLA) everywhere else.
-        An explicit or pinned ``"pallas"`` on a TPU, on a layout whose
-        kernel the TPU compiler rejects, raises unless ``interpret`` was
-        asked for: a chip never drops to interpret mode silently."""
+        pins it) is ``"scan"`` (XLA) on every layout and device: on a TPU
+        v5e the scan path's W-wide gathers beat the ragged scalar-core
+        kernel in every chipbench cell (``PERF_LEDGER.jsonl``).  An explicit
+        or pinned ``"pallas"`` on a TPU, on a layout whose kernel the TPU
+        compiler rejects (not in :data:`TPU_PALLAS_LAYOUTS`), raises unless
+        ``interpret`` was asked for: a chip never drops to interpret mode
+        silently."""
         backend = self.backend
-        on_tpu = jax.default_backend() == "tpu"
         if backend == "auto":
             env = os.environ.get(BACKEND_ENV_VAR, "").strip().lower()
-            if env in ("scan", "pallas"):
-                backend = env
-            elif on_tpu and self.layout in TPU_PALLAS_LAYOUTS:
-                return "pallas"
-            else:
+            if env not in ("scan", "pallas"):
                 return "scan"
+            backend = env
         if (
             backend == "pallas"
-            and on_tpu
+            and jax.default_backend() == "tpu"
             and self.interpret is None
             and self.layout not in TPU_PALLAS_LAYOUTS
         ):
@@ -1531,7 +1537,12 @@ class WalkEngine:
     def _transition(self, key, nodes, p_j, lipschitz, squeeze):
         """The proposal of :meth:`step`: the uniform draw, the layout's
         backend dispatch and the combine, for (W,) ``nodes``.  Returns
-        ``(next_nodes, hops, compact_overflow)``."""
+        ``(next_nodes, hops, compact_overflow)``.  Records the resolved
+        backend as one ``jax.monitoring`` event per trace
+        (``/repro/walk_transition/backend_<backend>``)."""
+        jax.monitoring.record_event(
+            span_event(WALK_TRANSITION_SCOPE, "backend_" + self.resolved_backend)
+        )
         p_j_t = self.p_j if p_j is None else p_j
         u = jax.random.uniform(
             key, (nodes.shape[0], num_uniforms(self.r)), jnp.float32

@@ -145,8 +145,8 @@ def walk_mhlj_batched(
 ):
     """W Algorithm-1 walks in one batched engine run.
 
-    One vectorized transition services all W walks per step (the Pallas
-    kernel on TPU, vmapped scan math elsewhere); returns (w, num_steps)
+    One vectorized transition services all W walks per step (the
+    engine's resolved backend: scan under ``"auto"``); returns (w, num_steps)
     update nodes + hops.
     """
     engine = WalkEngine(

@@ -45,9 +45,13 @@ What the TPU compiler accepts (compiled for a described v5e; see
 ``tests/test_tpu_compile.py``): only the ragged kernel.  The dense and
 sparse kernels (and the bucketed dispatch, which reuses the sparse one)
 take a ``cumsum``, which Mosaic does not lower, so they run in interpret
-mode off-TPU only, and on a TPU their layouts run the scan backend (XLA);
-``engine.TPU_PALLAS_LAYOUTS`` and ``WalkEngine.resolved_backend`` hold
-that rule.
+mode off-TPU only.  What a TPU runs under ``backend="auto"`` is the scan
+backend (XLA) on every layout, the ragged one included: measured on a TPU
+v5e, the ragged layout's W-wide XLA gathers beat this one-walk-at-a-time
+scalar-core kernel in every chipbench cell (``PERF_LEDGER.jsonl``,
+``PERF.md`` §6).  The ragged kernel runs compiled on a TPU only when
+``backend="pallas"`` asks for it; ``engine.TPU_PALLAS_LAYOUTS`` and
+``WalkEngine.resolved_backend`` hold that rule.
 
 When W is not a multiple of ``block_w`` the walk axis is padded up to the
 next block multiple and the padded lanes sliced off afterwards, so large

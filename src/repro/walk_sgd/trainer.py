@@ -348,7 +348,7 @@ def run_rw_sgd_multi(
     ``avg_every > 0`` averages the models across walks every that many
     updates (local-SGD style — the multi-walker regime of
     arXiv:2604.12260).  All W transitions per step are sampled by a
-    single ``WalkEngine.step`` call — the Pallas kernel on TPU — instead
+    single ``WalkEngine.step`` call — W-wide XLA gathers on TPU — instead
     of W independent scans.
 
     ``mesh`` (e.g. ``repro.launch.mesh.make_walker_mesh``) shards the

@@ -190,3 +190,34 @@ def test_backend_env_var_overrides_auto(setup, monkeypatch):
     ).step(key, nodes)
     np.testing.assert_array_equal(np.asarray(n_env), np.asarray(n_ref))
     np.testing.assert_array_equal(np.asarray(h_env), np.asarray(h_ref))
+
+
+@pytest.mark.parametrize("backend", ["auto", "pallas"])
+def test_transition_records_its_backend_once_per_trace(setup, monkeypatch, backend):
+    """Each trace of a ragged step records the backend it resolved as one
+    ``jax.monitoring`` event; a cached call records nothing."""
+    from repro.core.engine import BACKEND_ENV_VAR, WALK_TRANSITION_SCOPE, span_event
+
+    g, lips, params, _ = setup
+    monkeypatch.delenv(BACKEND_ENV_VAR, raising=False)
+    eng = WalkEngine.from_graph(
+        g, params, lipschitz=jnp.asarray(lips, jnp.float32), layout="ragged",
+        backend=backend, interpret=True if backend == "pallas" else None,
+    )
+    prefix = span_event(WALK_TRANSITION_SCOPE, "")
+    events = []
+
+    def on_event(event, **_):
+        if event.startswith(prefix):
+            events.append(event)
+
+    step = jax.jit(lambda e, key, nodes: e.step(key, nodes))
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        for w in (8, 8, 16):  # two traces: the second W=8 call hits the cache
+            nodes = jnp.arange(w, dtype=jnp.int32) % g.n
+            jax.block_until_ready(step(eng, jax.random.PRNGKey(w), nodes))
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+    resolved = "scan" if backend == "auto" else "pallas"
+    assert events == [f"/repro/walk_transition/backend_{resolved}"] * 2

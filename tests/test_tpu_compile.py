@@ -105,6 +105,26 @@ def test_ragged_engine_step_compiles(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 1
 
 
+def test_auto_ragged_engine_step_compiles_without_the_kernel(one_chip, monkeypatch):
+    """The jitted WalkEngine.step under "auto" on a TPU: the ragged
+    layout's W-wide XLA gathers, no Pallas kernel."""
+    g = _ragged_shapes(one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    nodes = jax.ShapeDtypeStruct((W,), jnp.int32, sharding=one_chip)
+    monkeypatch.delenv("REPRO_BACKEND", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def step(indptr, indices, edge_cdf, degrees, key, nodes):
+        eng = _ragged_engine(indptr, indices, edge_cdf, degrees)
+        assert eng.resolved_backend == "scan"
+        return eng.step(key, nodes)
+
+    compiled = jax.jit(step).lower(
+        g["indptr"], g["indices"], g["edge_cdf"], g["degrees"], key, nodes
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
 def test_mamba2_370m_decode_step_fits_one_chip(one_chip):
     """One full-width mamba2-370m decode step (48 layers, d_model 1024,
     vocab 50280) at the serving batch, within one chip's HBM."""
@@ -141,10 +161,9 @@ def test_mamba2_370m_decode_step_fits_one_chip(one_chip):
     assert 0 < used < HBM_BYTES
 
 
-def test_sharded_fleet_scan_runs_one_kernel_per_shard(topo):
-    """The mesh-sharded fleet scan on 4 described chips: the ragged kernel
-    runs once per device on W/4 walkers, and the periodic average is an
-    all-reduce."""
+def _sharded_fleet_scan_text(topo, **backend):
+    """HLO of the mesh-sharded fleet scan on 4 described chips, walkers
+    split over the mesh, the graph replicated."""
     from repro.walk_sgd import fleet as fleet_mod
     from repro.models import regression as reg
 
@@ -158,7 +177,7 @@ def test_sharded_fleet_scan_runs_one_kernel_per_shard(topo):
     g = {k: s(v.shape, v.dtype) for k, v in _ragged_shapes(None).items()}
     engine = _ragged_engine(
         g["indptr"], g["indices"], g["edge_cdf"], g["degrees"],
-        backend="pallas", interpret=False, walker_sharding=walkers,
+        walker_sharding=walkers, **backend,
     )
     engine = dataclasses.replace(engine, p_j=s((), jnp.float32))
     fleet = fleet_mod.WalkFleet(
@@ -166,7 +185,7 @@ def test_sharded_fleet_scan_runs_one_kernel_per_shard(topo):
         avg_every=4,
     )
     dim, steps = 10, 8
-    compiled = fleet_mod._fleet_scan.lower(
+    return fleet_mod._fleet_scan.lower(
         s((2,), jnp.uint32),
         s((W, dim), jnp.float32, walkers),
         s((BA_N, dim), jnp.float32),
@@ -178,8 +197,13 @@ def test_sharded_fleet_scan_runs_one_kernel_per_shard(topo):
         s((steps,), jnp.float32),
         True,
         reg.linear_grad,
-    ).compile()
-    text = compiled.as_text()
+    ).compile().as_text()
+
+
+def test_sharded_fleet_scan_runs_one_kernel_per_shard(topo):
+    """Asked for, the ragged kernel runs once per device on W/4 walkers,
+    and the periodic average is an all-reduce."""
+    text = _sharded_fleet_scan_text(topo, backend="pallas", interpret=False)
     kernel_calls = [
         line for line in text.splitlines()
         if "custom_call_target=\"tpu_custom_call\"" in line
@@ -189,11 +213,23 @@ def test_sharded_fleet_scan_runs_one_kernel_per_shard(topo):
     assert "all-reduce" in text
 
 
+def test_auto_sharded_fleet_scan_compiles(topo, monkeypatch):
+    """Under "auto" on a TPU the sharded fleet runs the XLA gathers on
+    each device's W/4 walkers, no kernel, and still averages by
+    all-reduce."""
+    monkeypatch.delenv("REPRO_BACKEND", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _sharded_fleet_scan_text(topo)
+    assert "tpu_custom_call" not in text
+    assert f"s32[{W // 4}]" in text
+    assert "all-reduce" in text
+
+
 @pytest.mark.parametrize("layout", ["ragged", "sparse", "dense", "bucketed"])
 def test_resolved_backend_tpu_rule(monkeypatch, layout):
-    """"auto" is the compiled kernel on TPU only where Mosaic accepts it,
-    XLA (scan) elsewhere; an explicit pallas on a rejected layout raises
-    on TPU unless interpret mode was asked for."""
+    """"auto" is XLA (scan) on every layout, TPU included; an explicit or
+    pinned pallas runs compiled on TPU where Mosaic accepts the kernel and
+    raises on a rejected layout unless interpret mode was asked for."""
     g = barabasi_albert(40, 2, seed=0, layout="csr")
     if layout == "bucketed":
         g = g.to_bucketed()
@@ -204,12 +240,13 @@ def test_resolved_backend_tpu_rule(monkeypatch, layout):
     monkeypatch.delenv("REPRO_BACKEND", raising=False)
     assert eng.resolved_backend == "scan"  # off TPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = layout in TPU_PALLAS_LAYOUTS
-    assert eng.resolved_backend == ("pallas" if compiled else "scan")
+    assert eng.resolved_backend == "scan"
     assert not eng.resolved_interpret
     pinned = dataclasses.replace(eng, backend="pallas")
-    if compiled:
+    if layout in TPU_PALLAS_LAYOUTS:
         assert pinned.resolved_backend == "pallas"
+        monkeypatch.setenv("REPRO_BACKEND", "pallas")
+        assert eng.resolved_backend == "pallas"
     else:
         with pytest.raises(ValueError, match=layout):
             pinned.resolved_backend
