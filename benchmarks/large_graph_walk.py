@@ -159,7 +159,8 @@ def _resident_table_bytes(engine: WalkEngine) -> int:
                   engine.edge_cdf):
         if field is not None:
             total += int(field.nbytes)
-    for group in (engine.bucket_neighbors, engine.bucket_rows):
+    for group in (engine.bucket_neighbors, engine.bucket_rows,
+                  engine.cdf_tiles):
         if group is not None:
             total += sum(int(a.nbytes) for a in group)
     return total

@@ -48,9 +48,11 @@ Backends (identical law, bitwise-identical outputs given the same key):
   a ``lax.cond`` fallback to the full dispatch on capacity overflow;
   ``"ragged"`` is the true-degree layout — resident row state is one flat
   per-edge CDF buffer aligned with the CSR ``indices`` (exactly O(E), no
-  padded and no per-bucket table), the MH inversion is a binary search of
-  each walk's own CDF segment (:func:`ragged_mh_invert`, O(W·log max_deg)
-  per step instead of O(W·max_deg)), and the pallas path is one fused
+  padded and no per-bucket table), the MH inversion is a lower-bound
+  search of each walk's own CDF segment over aligned 128-wide tiles of
+  that buffer (:func:`ragged_mh_invert`: one W-wide gather of whole tiles
+  per level, one level while max_deg <= 129 and two up to 16,512, instead
+  of O(W·max_deg) row work), and the pallas path is one fused
   scalar-core kernel per walk tile
   (``kernels.walk_transition.walk_transition_ragged``) that performs the
   inversion, the r-hop Lévy gather and the jump/MH combine in a single
@@ -122,6 +124,9 @@ __all__ = [
     "mh_cdf_invert",
     "ragged_edge_cdf",
     "ragged_edge_cdf_update",
+    "CDF_TILE",
+    "cdf_search_levels",
+    "edge_cdf_tiles",
     "ragged_mh_invert",
     "combine_bucketed",
     "bucket_capacities",
@@ -164,12 +169,18 @@ BACKEND_ENV_VAR = "REPRO_BACKEND"
 WALK_TRANSITION_SCOPE = "walk_transition"
 EDGE_CDF_BUILD_SPAN = "edge_cdf_build"
 
+# Width of the aligned tiles the ragged MH move searches (ragged_mh_invert):
+# the TPU's 128 lanes, so one tile is one lane-aligned row of a (m, 128)
+# table and each search level is one W-wide gather of whole rows.
+CDF_TILE = 128
+
 
 def span_event(span: str, counter: str = "seconds") -> str:
     """The ``jax.monitoring`` event of a host span or device scope:
     ``seconds`` carries a span's duration, any other counter is one event
     per occurrence inside it (for the ``walk_transition`` scope, one
-    ``backend_<backend>`` event each time a transition is traced)."""
+    ``backend_<backend>`` event each time a transition is traced, and on
+    the ragged layout's XLA path one ``mh_search_levels_<L>`` event)."""
     return f"/repro/{span}/{counter}"
 
 
@@ -538,46 +549,100 @@ def ragged_edge_cdf_update(
     return jnp.asarray(out)
 
 
+def cdf_search_levels(max_degree: int, tile: int = CDF_TILE) -> int:
+    """Levels L of the tiled CDF search for rows of up to ``max_degree``
+    entries: the least L >= 1 with ``max_degree <= (tile + 1) * tile **
+    (L - 1)``, so that the blocks of ``tile ** (L - 1)`` entries one row
+    touches always lie in two adjacent aligned tiles of the top level."""
+    levels = 1
+    while max_degree > (tile + 1) * tile ** (levels - 1):
+        levels += 1
+    return levels
+
+
+@functools.partial(jax.jit, static_argnames=("max_degree", "tile"))
+def edge_cdf_tiles(
+    edge_cdf: jnp.ndarray, *, max_degree: int, tile: int = CDF_TILE
+) -> Tuple[jnp.ndarray, ...]:
+    """The tile tables of :func:`ragged_mh_invert`, derived from the flat
+    per-edge CDF — one ``(m_l, tile)`` float32 array per search level.
+
+    Level 0 is ``edge_cdf`` itself, padded to whole tiles plus one and
+    viewed as rows of ``tile``; level ``l + 1`` holds the last entry of
+    every level-``l`` tile (the CDF at the end of each aligned block of
+    ``tile ** (l + 1)`` entries), padded and viewed the same way.  There
+    are :func:`cdf_search_levels` levels.  Pad entries are never read as
+    values: the search masks entries by position, not by value.
+    """
+    level = edge_cdf.astype(jnp.float32)
+    tiles = []
+    for _ in range(cdf_search_levels(max_degree, tile)):
+        rows = -(-level.shape[0] // tile) + 1
+        table = jnp.pad(level, (0, rows * tile - level.shape[0]))
+        tiles.append(table.reshape(rows, tile))
+        level = tiles[-1][:, -1]
+    return tuple(tiles)
+
+
 def ragged_mh_invert(
     indptr: jnp.ndarray,  # (n+1,) int32 CSR row pointers
     degrees: jnp.ndarray,  # (n,) int32
     indices: jnp.ndarray,  # (nnz,) int32 CSR neighbor ids
     edge_cdf: jnp.ndarray,  # (nnz,) float32 flat per-edge CDF
+    cdf_tiles: Tuple[jnp.ndarray, ...],  # edge_cdf_tiles(edge_cdf, ...)
     nodes: jnp.ndarray,  # (W,) int32 current node per walk
     u_mh: jnp.ndarray,  # (W,) the U_MH uniform per walk
-    *,
-    max_degree: int,
 ) -> jnp.ndarray:
-    """THE ragged MH-move inversion: binary-search each walk's own CDF
-    segment at its true degree; returns ``v_mh`` (W,).
+    """THE ragged MH-move inversion: a lower-bound search of each walk's
+    own CDF segment over aligned tiles; returns ``v_mh`` (W,).
 
     The padded layouts count ``cdf < u · cdf[-1]`` across the full row
-    width; over a non-decreasing CDF that count is a lower bound, so the
-    same index falls out of a binary search of the row's true-degree
-    segment ``edge_cdf[indptr[v] : indptr[v] + deg(v)]`` — pad slots
-    (trailing exact-total entries on the padded row, ``u < 1`` strictly)
-    never counted anyway.  ceil(log2(max_degree + 1)) rounds of W-wide
-    gathers replace the O(W·max_deg) row materialization; given the flat
-    CDF of :func:`ragged_edge_cdf` the returned neighbor is bitwise-equal
-    to :func:`mh_cdf_invert` on the padded row per key.  This is both the
-    scan backend's ragged MH move and the oracle the fused scalar-prefetch
-    kernel (``kernels.walk_transition.walk_transition_ragged``) mirrors
-    per walk.
+    width; over a non-decreasing CDF that count is the lower bound of
+    ``t = u · total`` in the row's true-degree segment
+    ``edge_cdf[s : e]`` (``s = indptr[v]``, ``e = s + deg(v)``) — pad
+    slots (trailing exact-total entries on the padded row, ``u < 1``
+    strictly) never counted anyway.  The search finds that bound level by
+    level in the tables of :func:`edge_cdf_tiles`: at the top level it
+    reads the two adjacent aligned tiles that hold every block the row
+    touches, at each level below the one tile inside the block found
+    above, and counts the entries that end a block inside the row (masked
+    by position, never by value) and are ``< t``.  That count is the
+    first block holding an entry ``>= t``; at level 0 it is the entry.
+    Each level is one W-wide gather of whole tiles (``(W, 2, tile)`` at
+    the top, ``(W, tile)`` below) in place of ~log2(tile) dependent
+    one-word rounds.  Given the flat CDF of :func:`ragged_edge_cdf` the
+    returned neighbor is bitwise-equal to :func:`mh_cdf_invert` on the
+    padded row per key.  This is the scan backend's ragged MH move, and
+    through ``kernels.walk_transition.ref`` the oracle of the fused
+    kernel (``walk_transition_ragged``), which binary-searches the flat
+    CDF per walk to the same bound.
     """
+    tile = cdf_tiles[0].shape[1]
+    top = len(cdf_tiles) - 1
     start = indptr[nodes]
     deg = degrees[nodes]
-    total = edge_cdf[start + deg - 1]
-    t = u_mh * total
-    lo = jnp.zeros_like(deg)
-    hi = deg
-    for _ in range(max(1, math.ceil(math.log2(max_degree + 1)))):
-        active = lo < hi
-        mid = (lo + hi) // 2
-        c = edge_cdf[start + jnp.minimum(mid, deg - 1)]
-        pred = active & (c < t)
-        lo = jnp.where(pred, mid + 1, lo)
-        hi = jnp.where(active & ~pred, mid, hi)
-    idx = jnp.minimum(lo, deg - 1)
+    end = start + deg
+    t = (u_mh * edge_cdf[end - 1])[:, None]
+
+    def first_at_or_above(vals, block0, level):
+        # vals: the ends of blocks block0 + k of tile ** level entries; the
+        # block that holds the first entry >= t, counted from the first
+        # of them that ends in-row
+        size = tile**level
+        lo, hi = start // size, end // size
+        block = block0[:, None] + jnp.arange(vals.shape[1], dtype=block0.dtype)
+        in_row = (block >= lo[:, None]) & (block < hi[:, None])
+        below = jnp.sum((in_row & (vals < t)).astype(jnp.int32), axis=1)
+        return jnp.maximum(lo, block0) + below
+
+    pair = start // tile ** (top + 1)
+    vals = cdf_tiles[top][pair[:, None] + jnp.arange(2, dtype=pair.dtype)]
+    found = first_at_or_above(
+        vals.reshape(vals.shape[0], 2 * tile), pair * tile, top
+    )
+    for level in range(top - 1, -1, -1):
+        found = first_at_or_above(cdf_tiles[level][found], found * tile, level)
+    idx = jnp.minimum(found - start, deg - 1)
     return indices[start + idx]
 
 
@@ -804,7 +869,9 @@ class WalkEngine:
     bucket_rows: Optional[Tuple[jnp.ndarray, ...]] = None  # (n_b, w_b) P_IS
     # -- ragged-layout state (the O(E) true-degree path) --------------------
     edge_cdf: Optional[jnp.ndarray] = None  # (nnz,) float32 flat per-edge CDF
-    max_degree: Optional[int] = None  # static bound for the binary search
+    cdf_tiles: Optional[Tuple[jnp.ndarray, ...]] = None  # edge_cdf_tiles:
+    #   the (m_l, CDF_TILE) tables the scan backend's MH move searches
+    max_degree: Optional[int] = None  # static: longest row
     cdf_width: Optional[int] = None  # width edge_cdf was materialized at —
     #   XLA reduction bits depend on the padded row width, so incremental
     #   churn must keep patching at THIS width (>= max_degree) to stay
@@ -885,6 +952,7 @@ class WalkEngine:
                 core.indptr, core.indices, core.degrees,
                 row_probs=row_probs, lipschitz=lipschitz,
             )
+            max_degree = int(np.asarray(core.degrees).max())
             return cls(
                 neighbors=None,
                 degrees=degrees,
@@ -901,8 +969,9 @@ class WalkEngine:
                 indptr=jnp.asarray(core.indptr, jnp.int32),
                 indices=jnp.asarray(core.indices, jnp.int32),
                 edge_cdf=edge_cdf,
-                max_degree=int(np.asarray(core.degrees).max()),
-                cdf_width=int(np.asarray(core.degrees).max()),
+                cdf_tiles=edge_cdf_tiles(edge_cdf, max_degree=max_degree),
+                max_degree=max_degree,
+                cdf_width=max_degree,
             )
         if layout == "bucketed":
             # bucket_factor=None keeps an already-bucketed graph's ladder
@@ -1107,6 +1176,7 @@ class WalkEngine:
             indptr=jnp.asarray(graph.indptr, jnp.int32),
             indices=jnp.asarray(graph.indices, jnp.int32),
             edge_cdf=new_cdf,
+            cdf_tiles=edge_cdf_tiles(new_cdf, max_degree=new_max),
             max_degree=new_max,
             cdf_width=new_width,
             graph_version=self.graph_version + 1,
@@ -1189,7 +1259,7 @@ class WalkEngine:
         if self.layout == "ragged":
             raise ValueError(
                 "the ragged layout has no full-width rows; the MH move "
-                "binary-searches the flat per-edge CDF (ragged_mh_invert)"
+                "searches the flat per-edge CDF (ragged_mh_invert)"
             )
         if self.row_probs is not None:
             return self.row_probs[nodes]
@@ -1539,7 +1609,9 @@ class WalkEngine:
         backend dispatch and the combine, for (W,) ``nodes``.  Returns
         ``(next_nodes, hops, compact_overflow)``.  Records the resolved
         backend as one ``jax.monitoring`` event per trace
-        (``/repro/walk_transition/backend_<backend>``)."""
+        (``/repro/walk_transition/backend_<backend>``), and on the ragged
+        layout's XLA path the levels of its tiled CDF search
+        (``/repro/walk_transition/mh_search_levels_<L>``)."""
         jax.monitoring.record_event(
             span_event(WALK_TRANSITION_SCOPE, "backend_" + self.resolved_backend)
         )
@@ -1554,9 +1626,9 @@ class WalkEngine:
         overflow = jnp.asarray(False)
 
         if self.layout == "ragged":
-            # true-degree path: the MH move binary-searches the flat
-            # per-edge CDF; resident row state is exactly O(E).  No bucket
-            # ladder, no compaction sort/scatter, no overflow cond.
+            # true-degree path: the MH move searches the flat per-edge
+            # CDF; resident row state is O(E).  No bucket ladder, no
+            # compaction sort/scatter, no overflow cond.
             if self.edge_cdf is None:
                 raise ValueError(
                     "ragged engine has no flat per-edge CDF; build it via "
@@ -1583,9 +1655,19 @@ class WalkEngine:
                     nodes, self.indptr, self.indices, self.edge_cdf, u
                 )
             else:
+                if self.cdf_tiles is None:
+                    raise ValueError(
+                        "ragged engine has no CDF tile tables; build it "
+                        "via from_graph, or pass cdf_tiles=edge_cdf_tiles("
+                        "edge_cdf, max_degree=...)"
+                    )
+                jax.monitoring.record_event(span_event(
+                    WALK_TRANSITION_SCOPE,
+                    f"mh_search_levels_{len(self.cdf_tiles)}",
+                ))
                 v_mh = ragged_mh_invert(
                     self.indptr, self.degrees, self.indices, self.edge_cdf,
-                    nodes, u[:, U_MH], max_degree=self.max_degree,
+                    self.cdf_tiles, nodes, u[:, U_MH],
                 )
                 v_jump, d = levy_jump_batched(
                     nodes, u, None, self.degrees, self.p_d, self.r,
@@ -1721,7 +1803,7 @@ class WalkEngine:
 _ENGINE_DATA_FIELDS = (
     "neighbors", "degrees", "p_j", "row_probs",
     "indptr", "indices", "node_bucket", "node_slot",
-    "bucket_neighbors", "bucket_rows", "edge_cdf",
+    "bucket_neighbors", "bucket_rows", "edge_cdf", "cdf_tiles",
 )
 _ENGINE_META_FIELDS = (
     "p_d", "r", "backend", "layout", "block_w", "interpret",

@@ -12,6 +12,7 @@ from repro.core.engine import (
     U_MH,
     combine_bucketed,
     combine_mh_jump,
+    edge_cdf_tiles,
     levy_jump_batched,
     mh_cdf_invert,
     mhlj_transition_math,
@@ -75,12 +76,14 @@ def walk_transition_ragged_ref(
     max_degree: int,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Same contract as ``kernel.walk_transition_ragged``: the engine's
-    flat-CDF binary-search MH move (``engine.ragged_mh_invert``), the
-    CSR-gathered Lévy branch and the jump/MH combine — the fused kernel
-    mirrors this composition per walk."""
+    tiled lower-bound search of the flat CDF (``engine.ragged_mh_invert``
+    over ``engine.edge_cdf_tiles``), the CSR-gathered Lévy branch and the
+    jump/MH combine — the fused kernel mirrors this composition per walk,
+    finding the same lower bound by binary search."""
     v_mh = ragged_mh_invert(
-        indptr, degrees, indices, edge_cdf, nodes, uniforms[:, U_MH],
-        max_degree=max_degree,
+        indptr, degrees, indices, edge_cdf,
+        edge_cdf_tiles(edge_cdf, max_degree=max_degree),
+        nodes, uniforms[:, U_MH],
     )
     v_jump, d = levy_jump_batched(
         nodes, uniforms, None, degrees, p_d, r, csr=(indptr, indices)

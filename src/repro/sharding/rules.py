@@ -335,9 +335,10 @@ def fleet_specs(fleet, mesh: Mesh, profile_name: str = "fleet"):
     """Spec tree matching a ``repro.walk_sgd.fleet.WalkFleet``: the walk
     ``nodes`` ride the walker axis, every engine leaf (padded neighbor
     tables, ragged CSR ``indptr``/``indices`` row state, the flat per-edge
-    ``edge_cdf``) is **replicated** — walker positions are data-dependent
-    gathers into the graph, so keeping graph state whole on every device
-    avoids cross-device gathers on the walk's hot path."""
+    ``edge_cdf`` and its ``cdf_tiles``) is **replicated** — walker
+    positions are data-dependent gathers into the graph, so keeping graph
+    state whole on every device avoids cross-device gathers on the walk's
+    hot path."""
     import dataclasses
 
     wspec = walker_batch_specs(

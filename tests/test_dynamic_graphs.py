@@ -349,6 +349,43 @@ def test_churn_width_escalation_rebuilds_at_new_width():
     )
 
 
+@pytest.mark.parametrize("path", ["patch", "escalation"])
+def test_churn_cdf_tiles_equal_a_fresh_engine(path):
+    """The CDF tile tables the MH move searches follow the churn: after
+    ``apply_churn`` — a segment patch when the max degree holds, a full
+    rebuild when an insert onto the hub raises it past ``cdf_width`` —
+    every level equals, bit for bit, that of a fresh ``from_graph`` on the
+    churned graph.  A 151-edge hub gives two search levels."""
+    n = 200
+    ring = np.arange(n, dtype=np.int64)
+    spokes = np.arange(2, 151, dtype=np.int64)
+    core = from_edges(
+        n,
+        np.concatenate([ring, np.zeros_like(spokes)]),
+        np.concatenate([(ring + 1) % n, spokes]),
+        layout="ragged",
+    )
+    lips_j = jnp.asarray(
+        np.exp(np.random.default_rng(3).normal(0.0, 1.0, n)), jnp.float32
+    )
+    eng = WalkEngine.from_graph(
+        core, PARAMS, lipschitz=lips_j, backend="scan", layout="ragged"
+    )
+    assert len(eng.cdf_tiles) == 2
+    ins = {"patch": [[50, 120]], "escalation": [[0, 160], [0, 170]]}[path]
+    core2, churn = apply_edge_churn(core, insert=np.asarray(ins, np.int64))
+    eng2 = eng.apply_churn(core2, churn, lipschitz=lips_j)
+    assert (eng2.cdf_width > eng.cdf_width) == (path == "escalation")
+    fresh = WalkEngine.from_graph(
+        core2, PARAMS, lipschitz=lips_j, backend="scan", layout="ragged"
+    )
+    assert len(eng2.cdf_tiles) == len(fresh.cdf_tiles) == 2
+    for got, want in zip(eng2.cdf_tiles, fresh.cdf_tiles):
+        np.testing.assert_array_equal(
+            np.asarray(got).view(np.int32), np.asarray(want).view(np.int32)
+        )
+
+
 def test_churn_sticky_width_when_max_degree_drops():
     """Deleting hub edges lowers the graph's max degree; the engine keeps
     its recorded ``cdf_width`` (sticky — never shrinks) and the patched

@@ -204,7 +204,7 @@ def test_transition_records_its_backend_once_per_trace(setup, monkeypatch, backe
         g, params, lipschitz=jnp.asarray(lips, jnp.float32), layout="ragged",
         backend=backend, interpret=True if backend == "pallas" else None,
     )
-    prefix = span_event(WALK_TRANSITION_SCOPE, "")
+    prefix = span_event(WALK_TRANSITION_SCOPE, "backend_")
     events = []
 
     def on_event(event, **_):
@@ -221,3 +221,86 @@ def test_transition_records_its_backend_once_per_trace(setup, monkeypatch, backe
         jax.monitoring.unregister_event_listener(on_event)
     resolved = "scan" if backend == "auto" else "pallas"
     assert events == [f"/repro/walk_transition/backend_{resolved}"] * 2
+
+
+def _search_case(name):
+    """Padded probability rows, matching padded neighbor rows and the
+    degrees of one test graph for the tiled ragged search."""
+    from repro.core import barabasi_albert, grid2d, star
+
+    rng = np.random.default_rng(11)
+    if name == "rows":
+        # hand-made rows: degree 1, rows that start or end on 8- and
+        # 128-entry tile boundaries, a 500-entry hub, and ~30% zero
+        # probabilities (ties in the CDF)
+        degrees = np.asarray(
+            [1, 7, 8, 1, 111, 128, 256, 1, 3, 500, 5, 1, 64, 2, 1], np.int64
+        )
+        probs = rng.random((degrees.size, degrees.max()))
+        probs[rng.random(probs.shape) < 0.3] = 0.0
+        probs[:, 0] += 1e-3  # every row has mass
+        probs[np.arange(degrees.max())[None, :] >= degrees[:, None]] = 0.0
+        rows = probs / probs.sum(axis=1, keepdims=True)
+        neighbors = np.arange(rows.size).reshape(rows.shape) % 977
+        return rows.astype(np.float32), neighbors.astype(np.int32), degrees
+    g = {
+        "star": lambda: star(300),
+        "grid": lambda: grid2d(25, 40),
+        "ba": lambda: barabasi_albert(200, 3, seed=1),
+    }[name]()
+    lips = np.exp(rng.normal(0.0, 1.0, g.n))
+    rows = row_probs_padded(mh_importance(g, lips), g)
+    return rows, np.asarray(g.neighbors), np.asarray(g.degrees, np.int64)
+
+
+@pytest.mark.parametrize("u_kind", ["zero", "top", "random"])
+@pytest.mark.parametrize(
+    "name,tile,levels",
+    [
+        ("star", 8, 3), ("star", 128, 2),
+        ("grid", 8, 1), ("grid", 128, 1),
+        ("ba", 8, 2), ("ba", 128, 1),
+        ("rows", 8, 3), ("rows", 128, 2),
+    ],
+)
+def test_tiled_ragged_search_equals_padded_inversion(name, tile, levels, u_kind):
+    """The ragged MH move's tiled lower-bound search over the flat CDF
+    (``ragged_mh_invert`` over ``edge_cdf_tiles``) returns, bit for bit,
+    the neighbor ``mh_cdf_invert`` picks on the padded row, at 1, 2 and 3
+    search levels, for u = 0, u = 1 - ulp and random u."""
+    from repro.core.engine import (
+        cdf_search_levels,
+        edge_cdf_tiles,
+        mh_cdf_invert,
+        ragged_edge_cdf,
+        ragged_mh_invert,
+    )
+
+    rows, neighbors, degrees = _search_case(name)
+    max_deg = int(degrees.max())
+    indptr = np.concatenate([[0], np.cumsum(degrees)])
+    keep = np.arange(rows.shape[1])[None, :] < degrees[:, None]
+    indices = neighbors[keep]
+    edge_cdf = ragged_edge_cdf(indptr, indices, degrees, row_probs=rows)
+    tiles = edge_cdf_tiles(edge_cdf, max_degree=max_deg, tile=tile)
+    assert len(tiles) == cdf_search_levels(max_deg, tile) == levels
+    if name == "rows":  # the boundary rows exist at this tile width
+        assert (indptr[1:-1] % tile == 0).any()
+        assert (degrees[indptr[:-1] % tile == 0] > 1).any()
+
+    n = degrees.size
+    nodes = jnp.asarray(np.tile(np.arange(n), 8), jnp.int32)
+    w = nodes.shape[0]
+    u = {
+        "zero": jnp.zeros((w,), jnp.float32),
+        "top": jnp.full((w,), np.nextafter(np.float32(1), np.float32(0))),
+        "random": jax.random.uniform(jax.random.PRNGKey(tile + n), (w,)),
+    }[u_kind]
+    want = mh_cdf_invert(
+        jnp.asarray(rows)[nodes], jnp.asarray(neighbors)[nodes], u
+    )
+    got = jax.jit(ragged_mh_invert)(
+        jnp.asarray(indptr, jnp.int32), jnp.asarray(degrees, jnp.int32),
+        jnp.asarray(indices, jnp.int32), edge_cdf, tiles, nodes, u,
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

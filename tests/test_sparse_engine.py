@@ -27,11 +27,14 @@ Four claims:
    per key — from a shared padded row table, from the flat numpy
    builders, and from a live lipschitz vector; on hub-heavy/trap-prone
    graphs, at bucket-boundary degrees, and at W values that are not
-   block multiples — and its resident state is *exactly* O(E): every
-   engine array is one-dimensional (no padded, no per-bucket table), and
+   block multiples — and its resident state is O(E): every engine array
+   but the CDF's 128-wide tile tables is one-dimensional (no padded, no
+   per-bucket table), and
    ``from_edges(layout="ragged")`` builds a graph that never carries a
    padded tensor at all.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -560,11 +563,13 @@ def test_ragged_rows_from_table_flat_builder_and_lipschitz_agree():
 
 def test_ragged_engine_resident_state_is_exactly_o_e():
     """The exactly-O(E) guarantee: a ragged engine carries no padded and
-    no per-bucket table — every array leaf is one-dimensional with at most
-    nnz + n + 1 entries — and a ``from_edges(layout='ragged')`` graph
-    never holds a padded tensor at all.  Asking for full-width rows
-    raises."""
+    no per-bucket table — every array leaf but the CDF tile tables is
+    one-dimensional with at most nnz + n + 1 entries, and the tile tables
+    are the flat CDF itself viewed in rows of ``CDF_TILE`` plus at most two
+    tiles of padding — and a ``from_edges(layout='ragged')`` graph never
+    holds a padded tensor at all.  Asking for full-width rows raises."""
     from repro.core import from_edges
+    from repro.core.engine import CDF_TILE
 
     idx = np.arange(200, dtype=np.int64)
     graph = from_edges(
@@ -579,10 +584,17 @@ def test_ragged_engine_resident_state_is_exactly_o_e():
     assert eng.neighbors is None and eng.row_probs is None
     assert eng.bucket_neighbors is None and eng.bucket_rows is None
     nnz, n = graph.num_edges, graph.n
-    for leaf in jax.tree_util.tree_leaves(eng):
+    for leaf in jax.tree_util.tree_leaves(
+        dataclasses.replace(eng, cdf_tiles=None)
+    ):
         assert jnp.ndim(leaf) <= 1  # nothing padded, nothing bucketed
         assert jnp.size(leaf) <= nnz + n + 1
     assert int(eng.edge_cdf.shape[0]) == nnz  # the O(E) row state, exactly
+    (tiles,) = eng.cdf_tiles  # max degree 3: one search level
+    assert tiles.shape[1] == CDF_TILE and tiles.size <= nnz + 2 * CDF_TILE
+    np.testing.assert_array_equal(
+        np.asarray(tiles).reshape(-1)[:nnz], np.asarray(eng.edge_cdf)
+    )
     with pytest.raises(ValueError, match="ragged layout"):
         eng.rows_table()
     with pytest.raises(ValueError, match="ragged layout"):

@@ -10,7 +10,9 @@ process at a time may load the TPU library, and every test worker imports
 this file.  All rehearsals stay in this one file so one worker owns them.
 """
 import dataclasses
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,13 +20,23 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-from repro.core.engine import TPU_PALLAS_LAYOUTS, WalkEngine
+from repro.core.engine import (
+    TPU_PALLAS_LAYOUTS,
+    WALK_TRANSITION_SCOPE,
+    WalkEngine,
+    edge_cdf_tiles,
+    span_event,
+)
 from repro.core.graphs import barabasi_albert
 from repro.core.transition import MHLJParams
 
 # barabasi_albert(100_000, 3, seed=0, layout="ragged"): the repo's
 # full-scale graph (benchmarks/large_graph_walk.py, serve_throughput.py)
 BA_N, BA_NNZ, BA_MAX_DEG = 100_000, 699_832, 1196
+# chipbench's kron16-mhlj (Graph500 Kronecker, scale 16, largest component)
+# and grid25x40-mhlj graphs: n, CSR entries with self-loops, max degree
+KRON16 = (46_756, 1_865_304, 9810)
+GRID25X40 = (1000, 4870, 5)
 W = 8192
 R = 3
 HBM_BYTES = 16 * 1024**3  # one v5e chip
@@ -48,23 +60,33 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _ragged_shapes(sharding):
+def _ragged_shapes(sharding, graph=(BA_N, BA_NNZ, BA_MAX_DEG)):
+    """Shapes of a ragged engine's graph state: CSR, flat CDF and the CDF
+    tile tables (``edge_cdf_tiles``) for ``graph = (n, nnz, max_deg)``."""
+    n, nnz, max_deg = graph
+
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
+    tiles = jax.eval_shape(
+        functools.partial(edge_cdf_tiles, max_degree=max_deg),
+        s((nnz,), jnp.float32),
+    )
     return dict(
-        indptr=s((BA_N + 1,), jnp.int32),
-        indices=s((BA_NNZ,), jnp.int32),
-        edge_cdf=s((BA_NNZ,), jnp.float32),
-        degrees=s((BA_N,), jnp.int32),
+        indptr=s((n + 1,), jnp.int32),
+        indices=s((nnz,), jnp.int32),
+        edge_cdf=s((nnz,), jnp.float32),
+        cdf_tiles=tuple(s(t.shape, t.dtype) for t in tiles),
+        degrees=s((n,), jnp.int32),
     )
 
 
-def _ragged_engine(indptr, indices, edge_cdf, degrees, **kw):
+def _ragged_engine(g, max_degree=BA_MAX_DEG, **kw):
     return WalkEngine(
-        neighbors=None, degrees=degrees, p_j=0.1, p_d=0.5, r=R,
-        layout="ragged", indptr=indptr, indices=indices, edge_cdf=edge_cdf,
-        max_degree=BA_MAX_DEG, cdf_width=BA_MAX_DEG, **kw,
+        neighbors=None, degrees=g["degrees"], p_j=0.1, p_d=0.5, r=R,
+        layout="ragged", indptr=g["indptr"], indices=g["indices"],
+        edge_cdf=g["edge_cdf"], cdf_tiles=g["cdf_tiles"],
+        max_degree=max_degree, cdf_width=max_degree, **kw,
     )
 
 
@@ -92,16 +114,11 @@ def test_ragged_engine_step_compiles(one_chip):
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
     nodes = jax.ShapeDtypeStruct((W,), jnp.int32, sharding=one_chip)
 
-    def step(indptr, indices, edge_cdf, degrees, key, nodes):
-        eng = _ragged_engine(
-            indptr, indices, edge_cdf, degrees,
-            backend="pallas", interpret=False,
-        )
+    def step(g, key, nodes):
+        eng = _ragged_engine(g, backend="pallas", interpret=False)
         return eng.step(key, nodes)
 
-    compiled = jax.jit(step).lower(
-        g["indptr"], g["indices"], g["edge_cdf"], g["degrees"], key, nodes
-    ).compile()
+    compiled = jax.jit(step).lower(g, key, nodes).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 1
 
 
@@ -114,15 +131,63 @@ def test_auto_ragged_engine_step_compiles_without_the_kernel(one_chip, monkeypat
     monkeypatch.delenv("REPRO_BACKEND", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-    def step(indptr, indices, edge_cdf, degrees, key, nodes):
-        eng = _ragged_engine(indptr, indices, edge_cdf, degrees)
+    def step(g, key, nodes):
+        eng = _ragged_engine(g)
         assert eng.resolved_backend == "scan"
         return eng.step(key, nodes)
 
-    compiled = jax.jit(step).lower(
-        g["indptr"], g["indices"], g["edge_cdf"], g["degrees"], key, nodes
-    ).compile()
+    compiled = jax.jit(step).lower(g, key, nodes).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def _auto_ragged_step(sharding, graph, monkeypatch):
+    """The jitted "auto" ragged step on a TPU for ``graph = (n, nnz,
+    max_deg)``, a walk from every node, lowered; and the
+    ``walk_transition`` events its trace recorded."""
+    g = _ragged_shapes(sharding, graph)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding)
+    nodes = jax.ShapeDtypeStruct((graph[0],), jnp.int32, sharding=sharding)
+    monkeypatch.delenv("REPRO_BACKEND", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prefix = span_event(WALK_TRANSITION_SCOPE, "")
+    events = []
+
+    def on_event(event, **_):
+        if event.startswith(prefix):
+            events.append(event)
+
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        lowered = jax.jit(
+            lambda g, key, nodes: _ragged_engine(g, graph[2]).step(key, nodes)
+        ).lower(g, key, nodes)
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+    return lowered, events
+
+
+def test_auto_ragged_step_searches_kron16_rows_in_tiles(one_chip, monkeypatch):
+    """At kron16's shapes the "auto" step's MH move is a two-level tiled
+    search: no kernel, and at most 9 gathers in the whole step (the
+    binary search took 21: 18 in the MH move, 3 in the hop loop)."""
+    lowered, _ = _auto_ragged_step(one_chip, KRON16, monkeypatch)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert len(re.findall(r"\bgather\(", text)) <= 9
+
+
+@pytest.mark.parametrize("graph,levels", [(KRON16, 2), (GRID25X40, 1)])
+def test_auto_ragged_step_records_its_search_levels(
+    one_chip, monkeypatch, graph, levels
+):
+    """Each trace of the "auto" ragged step records one backend event and
+    one ``mh_search_levels_<L>`` event: L = 2 on kron16 (max degree
+    9,810), L = 1 on the grid (max degree 5)."""
+    _, events = _auto_ragged_step(one_chip, graph, monkeypatch)
+    assert sorted(events) == [
+        "/repro/walk_transition/backend_scan",
+        f"/repro/walk_transition/mh_search_levels_{levels}",
+    ]
 
 
 def test_mamba2_370m_decode_step_fits_one_chip(one_chip):
@@ -174,11 +239,10 @@ def _sharded_fleet_scan_text(topo, **backend):
     def s(shape, dtype, sharding=repl):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    g = {k: s(v.shape, v.dtype) for k, v in _ragged_shapes(None).items()}
-    engine = _ragged_engine(
-        g["indptr"], g["indices"], g["edge_cdf"], g["degrees"],
-        walker_sharding=walkers, **backend,
+    g = jax.tree_util.tree_map(
+        lambda v: s(v.shape, v.dtype), _ragged_shapes(None)
     )
+    engine = _ragged_engine(g, walker_sharding=walkers, **backend)
     engine = dataclasses.replace(engine, p_j=s((), jnp.float32))
     fleet = fleet_mod.WalkFleet(
         engine=engine, nodes=s((W,), jnp.int32, walkers), num_walks=W,
